@@ -20,9 +20,10 @@ enclosing-loop multiplicity, and ring-model wire bytes (same byte formulas as
 ``roofline/hlo.py`` so jaxpr- and HLO-level accounting agree).
 
 A second pass (:func:`replication_taints`) is a replication checker for
-pre-vma jax (where shard_map runs with ``check_rep=False``): values seeded by
+shard_maps traced with ``check_vma=False`` (jax checks the others itself):
+values seeded by
 ``lax.axis_index`` or by a sharded input axis are tracked through the body;
-reaching a shard_map *output* whose out_names declare the value replicated
+reaching a shard_map *output* whose out_specs declare the value replicated
 over an axis it still (conservatively) varies on is a divergence violation —
 the bug class where per-device state leaks into a tensor the layout promises
 is identical everywhere.
@@ -37,6 +38,8 @@ from jax._src import core as jcore
 # primitive name -> normalized collective kind
 COLLECTIVE_PRIMS = {
     "psum": "psum",
+    "psum_invariant": "psum",          # psum of a varying value
+    "all_gather_invariant": "all_gather",
     "pmax": "pmax",
     "pmin": "pmin",
     "all_gather": "all_gather",
@@ -259,13 +262,13 @@ def extract_ir(closed_jaxpr, axis_sizes: dict | None = None) -> IRProgram:
 # replication-divergence taint analysis (rule c)
 # ---------------------------------------------------------------------------
 
-def _names_axes(names) -> set:
-    """Axis names appearing anywhere in a shard_map in/out names dict."""
+def _spec_axes(spec) -> set:
+    """Axis names appearing anywhere in a shard_map in/out PartitionSpec."""
     out: set = set()
-    for axes in (names or {}).values():
+    for axes in (spec or ()):
         if isinstance(axes, str):
             out.add(axes)
-        else:
+        elif axes is not None:
             out.update(axes)
     return out
 
@@ -401,33 +404,33 @@ def replication_taints(closed_jaxpr, *, seed_inputs: bool = True) -> list:
     """Run the divergence sanitizer over every shard_map in a closed jaxpr.
 
     Returns a list of violation dicts: shard_map outputs that (per the
-    conservative dataflow) may still vary over an axis their out_names
+    conservative dataflow) may still vary over an axis their out_specs
     declare replicated.  ``seed_inputs=False`` restricts seeding to
     ``axis_index`` (the ISSUE's literal rule c); the default additionally
     seeds each input's sharded axes, which makes the pass a full
-    replication checker for ``check_rep=False`` shard_maps.
+    replication checker for ``check_vma=False`` shard_maps.
     """
     prog = extract_ir(closed_jaxpr)
     violations = []
     for eqn, _mult, path in prog.shard_map_eqns:
         body = _as_jaxpr(eqn.params.get("jaxpr"))
-        if body is None:
-            continue
+        if body is None or eqn.params.get("check_vma"):
+            continue  # with check_vma, jax verified the out_specs itself
         mesh = eqn.params.get("mesh")
         sizes = mesh_axis_sizes(mesh) if mesh is not None else {}
         # a size-1 axis cannot diverge (axis_index over it is constant 0)
         mesh_axes = {a for a, n in sizes.items() if n > 1}
-        in_names = eqn.params.get("in_names", ())
-        out_names = eqn.params.get("out_names", ())
+        in_specs = eqn.params.get("in_specs", ())
+        out_specs = eqn.params.get("out_specs", ())
         in_taints = []
         for i, v in enumerate(body.invars):
-            if seed_inputs and i < len(in_names):
-                in_taints.append(frozenset(_names_axes(in_names[i])))
+            if seed_inputs and i < len(in_specs):
+                in_taints.append(frozenset(_spec_axes(in_specs[i])))
             else:
                 in_taints.append(frozenset())
         out_taints = _taint_jaxpr(body, in_taints)
         for i, t in enumerate(out_taints):
-            declared = _names_axes(out_names[i]) if i < len(out_names) \
+            declared = _spec_axes(out_specs[i]) if i < len(out_specs) \
                 else set()
             bad = (set(t) & mesh_axes) - declared
             if bad:

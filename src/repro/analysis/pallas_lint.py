@@ -13,6 +13,8 @@ its ``GridMapping`` statically — no kernel is ever run:
 * **tile divisibility** — block dims must divide the (padded) array dims;
   a partial trailing tile means the kernel reads/writes garbage lanes
   unless it masks, and every kernel in this repo pads instead.
+* **TPU tiling** — a block's last two dims must be multiples of (8, 128)
+  or the whole array dims, or the TPU compiler refuses the kernel.
 * **VMEM budget** — sum of live block bytes (inputs + outputs, x2 for the
   pipeline's double buffering) per kernel against the ~16 MiB/core VMEM of
   the TPU generations the roofline models; a kernel whose resident tiles
@@ -27,11 +29,15 @@ import jax
 import jax.numpy as jnp
 
 from jax._src import core as jcore
+from jax._src.pallas import core as pallas_core
 
 from .rules import Finding
 
 VMEM_BUDGET = 16 * 2 ** 20      # bytes/core; see /opt roofline + DESIGN §13
 DOUBLE_BUFFER = 2               # pallas pipelines blocks in/out
+# a block's last two dims must be multiples of the (8, 128) vreg tile or
+# the whole array dims (TPU compiler rule; interpret mode does not check)
+SUBLANES, LANES = 8, 128
 
 
 def find_pallas_eqns(closed_jaxpr) -> list:
@@ -68,8 +74,8 @@ def lint_grid_mapping(gm, kernel: str = "") -> tuple:
     vmem = 0
     n_skipped_maps = 0
     for bi, bm in enumerate(gm.block_mappings):
-        arr = bm.array_shape_dtype
-        block = tuple(int(b) for b in bm.block_shape)
+        arr = bm.array_aval
+        block = tuple(map(pallas_core.get_block_size, bm.block_shape))
         vmem += math.prod(block) * arr.dtype.itemsize
         if len(block) != len(arr.shape):
             findings.append(Finding(
@@ -84,6 +90,14 @@ def lint_grid_mapping(gm, kernel: str = "") -> tuple:
                     f"block #{bi} dim {d}: tile {bs} does not divide "
                     f"array dim {ad} — partial tile would read/write "
                     f"unmasked garbage lanes"))
+        for d, unit in ((-1, LANES), (-2, SUBLANES)):
+            if len(block) >= -d and block[d] % unit and \
+                    block[d] != arr.shape[d]:
+                findings.append(Finding(
+                    "pallas", kernel,
+                    f"block #{bi} dim {len(block) + d}: tile {block[d]} "
+                    f"is neither a multiple of {unit} nor the whole array "
+                    f"dim {arr.shape[d]} — the TPU compiler refuses it"))
         imj = bm.index_map_jaxpr
         if len(imj.jaxpr.invars) != len(grid):
             n_skipped_maps += 1     # scalar-prefetch-driven map
@@ -138,6 +152,7 @@ def lint_default_kernels() -> tuple:
     Shapes mirror tests/test_kernels.py: GQA flash (fwd and the two-pass
     bwd via grad) and the paged decode kernel.  Returns (findings, stats).
     """
+    from ..kernels.autotune import DEFAULT_TILES
     from ..kernels.flash_attention import flash_attention
     from ..kernels.paged_attention import paged_attention
 
@@ -149,7 +164,10 @@ def lint_default_kernels() -> tuple:
     v = sds((2, 2, 128, 64), jnp.float32)
 
     def floss(q, k, v):
-        return flash_attention(q, k, v, causal=True).sum()
+        # the tiles a TPU resolves (the CPU rows of the autotune table are
+        # interpret-mode timings and never reach the chip)
+        return flash_attention(q, k, v, causal=True, bq=DEFAULT_TILES[0],
+                               bk=DEFAULT_TILES[1]).sum()
 
     tr = jax.jit(jax.grad(floss, (0, 1, 2))).trace(q, k, v)
     f, s = lint_closed_jaxpr(tr.jaxpr, "flash_attention")

@@ -17,7 +17,7 @@ Each rule encodes one bug class this repo has actually shipped a fix for:
   stage-replicated leaves — shows up as the ``(..., 'pipe')`` set counting
   short.  Exact double-psum drift is caught by the SHARDCHECK.json baseline
   diff (counts here are >=: loss/metric psums legitimately share axis sets).
-* ``check_replication`` — the taint sanitizer: ``axis_index``-derived or
+* ``check_divergence`` — the taint sanitizer: ``axis_index``-derived or
   input-sharded values flowing to a shard_map output declared replicated
   over an axis they still vary on (collective_ir.replication_taints).
 
@@ -117,7 +117,7 @@ def check_grad_sync(prog: IRProgram, meta: dict, entry: str = "") -> list:
     return out
 
 
-def check_replication(closed_jaxpr, entry: str = "", *,
+def check_divergence(closed_jaxpr, entry: str = "", *,
                       seed_inputs: bool = True) -> list:
     """Divergence sanitizer over every shard_map in the trace."""
     out = []
@@ -139,5 +139,5 @@ def run_all(prog: IRProgram, meta: dict, closed_jaxpr=None,
     findings += check_layouts(meta, entry)
     findings += check_grad_sync(prog, meta, entry)
     if closed_jaxpr is not None:
-        findings += check_replication(closed_jaxpr, entry)
+        findings += check_divergence(closed_jaxpr, entry)
     return findings

@@ -9,72 +9,25 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # varying -> invariant gather (precise vma; values are identical copies)
-    from jax._src.lax.parallel import all_gather_invariant as _agi
-except ImportError:  # pragma: no cover - older jax
-    _agi = None
-
-# Does this jax track varying-manifest axes (vma) on avals?  Pre-vma releases
-# (<= 0.4.x) have neither jax.typeof nor lax.pvary; the *_v helpers below fall
-# back to full physical reductions there (every call site in this repo reduces
-# values that physically vary over the listed axes, so the fallback is exact).
-HAS_VMA = hasattr(jax, "typeof") and hasattr(lax, "pvary")
-_HAS_VMA = HAS_VMA  # back-compat alias
-
-
-# ---------------------------------------------------------------------------
-# shard_map compat: jax.shard_map (new) -> jax.sharding.shard_map ->
-# jax.experimental.shard_map.shard_map (<= 0.4.x)
-# ---------------------------------------------------------------------------
-
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn, True
-    fn = getattr(jax.sharding, "shard_map", None)
-    if fn is not None:
-        return fn, True
-    from jax.experimental.shard_map import shard_map as fn  # noqa: PLC0415
-    return fn, False
-
-
-_SHARD_MAP_IMPL, _SHARD_MAP_NEW = _resolve_shard_map()
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-    """Version-portable ``jax.shard_map``.
-
-    On pre-vma jax the experimental implementation is used with
-    ``check_rep=False``: this codebase is written against vma semantics
-    (custom_vjp collectives, psum-of-masked-value broadcasts) for which the
-    old replication checker has no rules.
-    """
-    if _SHARD_MAP_NEW:
-        return _SHARD_MAP_IMPL(f, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, **kw)
-    kw.pop("check_vma", None)
-    kw.setdefault("check_rep", False)
-    return _SHARD_MAP_IMPL(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
+# varying -> invariant gather (precise vma; values are identical copies)
+from jax._src.lax.parallel import all_gather_invariant as _agi
 
 
 def all_gather_inv(x, axes, *, axis=0, tiled=False):
     """all_gather whose output is vma-INVARIANT over the gathered axes
-    (every member of the group holds the same gathered value).  Falls back
-    to plain all_gather on jax versions without the primitive."""
+    (every member of the group holds the same gathered value).  Axes that
+    ``x`` does not vary on are skipped: its copies there are already equal."""
     if isinstance(axes, str):
         axes = (axes,)
-    if _agi is not None:
-        vma = vma_of(x)
-        ax = tuple(a for a in axes if a in vma)
-        if not ax:
-            return x
-        return _agi(x, ax, axis=axis, tiled=tiled)
-    return lax.all_gather(x, tuple(axes), axis=axis, tiled=tiled)
+    vma = vma_of(x)
+    ax = tuple(a for a in axes if a in vma)
+    if not ax:
+        return x
+    return _agi(x, ax, axis=axis, tiled=tiled)
 
 
 def pvary(x, axes):
-    """Mark ``x`` as varying over ``axes`` (compat shim for jax>=0.8).
+    """Mark ``x`` as varying over ``axes`` (idempotent ``lax.pcast``).
 
     Used at the step level on replicated params: pvary's transpose is a psum
     over ``axes``, which is exactly the deferred (fused) gradient reduction —
@@ -82,15 +35,18 @@ def pvary(x, axes):
     """
     if isinstance(axes, str):
         axes = (axes,)
-    if not _HAS_VMA:
-        return x  # no vma tracking: the annotation is a numerical no-op
-    axes = tuple(a for a in axes if a not in vma_of(x))  # idempotent
+    axes = tuple(dict.fromkeys(a for a in axes if a not in vma_of(x)))
     if not axes:
         return x
-    try:
-        return lax.pcast(x, tuple(axes), to="varying")
-    except (AttributeError, TypeError):
-        return lax.pvary(x, tuple(axes))
+    return lax.pcast(x, axes, to="varying")
+
+
+def unify_vma(*xs):
+    """pvary every array to the union of their vma.  A ``pallas_call``
+    inside ``shard_map`` declares one vma per output, so its operands are
+    brought to a common one first; outside ``shard_map`` this is a no-op."""
+    axes = tuple(sorted(frozenset().union(*map(vma_of, xs))))
+    return tuple(pvary(x, axes) for x in xs)
 
 
 def tree_pvary(tree, axes_tree):
@@ -131,31 +87,14 @@ grad_sync.defvjp(_gs_fwd, _gs_bwd)
 
 
 def vma_of(x) -> frozenset:
-    try:
-        return jax.typeof(x).vma
-    except AttributeError:
-        return frozenset()
+    return jax.typeof(x).vma
 
-
-def axis_size1(a) -> int:
-    """Static size of one named mesh axis (portable across jax versions)."""
-    try:
-        return lax.axis_size(a)
-    except AttributeError:  # pre-0.5 jax: psum of a literal folds to the size
-        return lax.psum(1, a)
 
 
 def _vary_axes(x, axes) -> tuple:
-    """Subset of ``axes`` that x varies on; all of them on pre-vma jax.
-
-    On pre-vma jax physical variance cannot be queried, so the reductions run
-    over every listed axis.  That is exact at every call site in this repo:
-    the psum_v inputs are genuine partial sums over those axes, and max / min
-    / mean of identical replicated copies are the copies themselves."""
+    """Subset of ``axes`` that x varies on."""
     if isinstance(axes, str):
         axes = (axes,)
-    if not _HAS_VMA:
-        return tuple(axes)
     vma = vma_of(x)
     return tuple(a for a in axes if a in vma)
 
@@ -190,7 +129,7 @@ def axis_size(axes):
         axes = (axes,)
     s = 1
     for a in axes:
-        s *= axis_size1(a)
+        s *= lax.axis_size(a)
     return s
 
 
@@ -200,7 +139,7 @@ def axis_linear_index(axes):
         axes = (axes,)
     idx = jnp.int32(0)
     for a in axes:
-        idx = idx * axis_size1(a) + lax.axis_index(a)
+        idx = idx * lax.axis_size(a) + lax.axis_index(a)
     return idx
 
 
@@ -264,7 +203,7 @@ def halo_exchange_left(x, axes, halo: int, axis: int):
     """
     if isinstance(axes, str):
         axes = (axes,)
-    sizes = [axis_size1(a) for a in axes]
+    sizes = [lax.axis_size(a) for a in axes]
     n = 1
     for s in sizes:
         n *= s

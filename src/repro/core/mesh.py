@@ -23,18 +23,12 @@ from .api import LOGICAL_AXES, LOGICAL_AXES_SEQ, ParallelContext
 
 
 def _axis_types(n):
-    try:
-        return (jax.sharding.AxisType.Auto,) * n
-    except AttributeError:  # older jax
-        return None
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_mesh(shape, axes):
-    kw = {}
-    at = _axis_types(len(axes))
-    if at is not None:
-        kw["axis_types"] = at
-    return jax.make_mesh(tuple(shape), tuple(axes), **kw)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=_axis_types(len(axes)))
 
 
 def logical_mesh(ctx: ParallelContext, devices=None) -> Mesh:
@@ -58,11 +52,7 @@ def logical_mesh(ctx: ParallelContext, devices=None) -> Mesh:
     else:
         arr = flat.reshape(ctx.data, ctx.depth, ctx.rows, ctx.cols)
         axes = LOGICAL_AXES
-    kw = {}
-    at = _axis_types(len(axes))
-    if at is not None:
-        kw["axis_types"] = at
-    return Mesh(arr, axes, **kw)
+    return Mesh(arr, axes, axis_types=_axis_types(len(axes)))
 
 
 def pipeline_mesh(ctx: ParallelContext, pipe: int, devices=None, *,
@@ -93,11 +83,7 @@ def pipeline_mesh(ctx: ParallelContext, pipe: int, devices=None, *,
             f"[q={ctx.rows},{ctx.cols},d={ctx.depth}], got {flat.size}")
     arr = flat.reshape(pipe, ctx.data, ctx.depth, ctx.rows, ctx.cols)
     axes = ("pipe",) + LOGICAL_AXES
-    kw = {}
-    at = _axis_types(5)
-    if at is not None:
-        kw["axis_types"] = at
-    return Mesh(arr, axes, **kw)
+    return Mesh(arr, axes, axis_types=_axis_types(len(axes)))
 
 
 def logical_from_production(prod_mesh: Mesh, ctx: ParallelContext) -> Mesh:

@@ -374,6 +374,10 @@ class TesseractOps:
 
         zero_axes = ((ctx.axis_data, ctx.axis_seq) if ctx.seq > 1
                      else (ctx.axis_data,))
+        # the sums also vary over whatever else the inputs vary on (the
+        # pipe axis of a pipeline stage), but never over the reduced axes
+        zero_axes += tuple(a for a in col.vma_of(xf) | col.vma_of(lab)
+                           if a not in model_axes)
         zero = col.pvary(jnp.float32(0), zero_axes)
         (loss_sum, count), _ = lax.scan(body, (zero, zero), (xc, lc, mc))
         return loss_sum, count

@@ -46,6 +46,7 @@ from jax import lax
 
 from .collectives import _ppermute_linear, axis_linear_index, axis_size
 from .summa import _perm_shift
+from ..kernels.ops import interpret_mode
 from ..kernels.flash_attention import (
     _M_FLOOR, NEG_INF, flash_dkv_step, flash_dq_step, flash_fwd_step)
 
@@ -260,7 +261,7 @@ _ring.defvjp(_ring_vjp_fwd, _ring_vjp_bwd)
 
 def ring_attention(q, k, v, *, axes, variant: str = "ring", causal: bool = True,
                    local_window: int = 0, softmax_scale=None,
-                   impl: str = "jnp", interpret: bool = True):
+                   impl: str = "jnp", interpret: bool | None = None):
     """Seq-sharded attention over the ring formed by mesh ``axes``.
 
     q: [B, Hq, L, D], k: [B, Hkv, Lk, D], v: [B, Hkv, Lk, Dv] — the LOCAL
@@ -283,7 +284,9 @@ def ring_attention(q, k, v, *, axes, variant: str = "ring", causal: bool = True,
                     causal=bool(causal), window=int(local_window),
                     scale=(None if softmax_scale is None
                            else float(softmax_scale)),
-                    impl=impl, interpret=bool(interpret))
+                    impl=impl,
+                    interpret=interpret_mode() if interpret is None
+                    else bool(interpret))
     return _ring(spec, q, k, v)
 
 

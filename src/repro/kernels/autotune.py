@@ -46,11 +46,8 @@ def _load_committed(path=COMMITTED_CACHE_PATH) -> int:
 def _backend_name() -> str:
     global _BACKEND
     if _BACKEND is None:
-        try:
-            import jax
-            _BACKEND = jax.default_backend()
-        except Exception:
-            _BACKEND = "cpu"
+        import jax
+        _BACKEND = jax.default_backend()
     return _BACKEND
 
 

@@ -41,6 +41,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.collectives import unify_vma, vma_of
+
 
 DEFAULT_BQ = 256
 DEFAULT_BK = 256
@@ -117,14 +119,25 @@ def _q_index(cfg: FlashCfg, kb, qi):
 
 
 def _block_mask(cfg: FlashCfg, rows, jj):
-    """(bq, bk) validity mask for KV block jj given q-row positions."""
+    """(bq, bk) validity mask for KV block jj given the (bq, 1) q-row
+    positions."""
     cols = jj * cfg.bk + lax.broadcasted_iota(jnp.int32, (cfg.bq, cfg.bk), 1)
     mask = cols < cfg.tk_real
     if cfg.causal:
-        mask &= rows[:, None] >= cols
+        mask &= rows >= cols
     if cfg.window > 0:
-        mask &= cols > rows[:, None] - cfg.window
+        mask &= cols > rows - cfg.window
     return mask
+
+
+def _rowmap(b, h, i, *_):
+    """Index map of a lane-dense [B, H, 1, T] row-statistics block."""
+    return (b, h, 0, i)
+
+
+def _lane_rows(x):
+    """[B, H, T] row statistics -> the kernels' lane-dense [B, H, 1, T]."""
+    return x.reshape(x.shape[0], x.shape[1], 1, x.shape[2])
 
 
 # ---------------------------------------------------------------------------
@@ -150,29 +163,31 @@ def _fwd_kernel(qpos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         k = k_ref[0, 0].astype(jnp.float32)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * cfg.scale
-        rows = qpos_ref[0]
-        s = jnp.where(_block_mask(cfg, rows, jj), s, NEG_INF)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - _msafe(m_new)[:, None])          # masked entries -> 0
+        s = jnp.where(_block_mask(cfg, qpos_ref[...].T, jj), s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - _msafe(m_new))                    # masked entries -> 0
         corr = jnp.exp(_msafe(m_prev) - _msafe(m_new))
-        l_ref[:, 0] = l_ref[:, 0] * corr + jnp.sum(p, axis=-1)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
         v = v_ref[0, 0].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + lax.dot_general(
+        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        m_ref[:, 0] = m_new
+        m_ref[...] = m_new
 
     @pl.when(j == cfg.nk - 1)
     def _done():
-        l = l_ref[:, 0]
+        l = l_ref[...]
         ls = jnp.where(l == 0.0, 1.0, l)                 # masked row -> 0 out
-        o_ref[0, 0] = (acc_ref[...] / ls[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = _msafe(m_ref[:, 0]) + jnp.log(ls)
+        o_ref[0, 0] = (acc_ref[...] / ls).astype(o_ref.dtype)
+        # row statistics leave the kernel lane-dense: (bq, 1) -> (1, bq)
+        lse_ref[0, 0] = (_msafe(m_ref[...]) + jnp.log(ls)).T
 
 
 def _fwd_call(cfg: FlashCfg, q, k, v, q_pos):
+    """-> (out [B, Hq, Tq, Dv], lse [B, Hq, Tq])."""
     B, Hq, Tq, D = q.shape
     Dv = v.shape[-1]
+    vma = vma_of(q)
     grid = (B, Hq, cfg.nq, cfg.nk)
     qmap = lambda b, h, i, j: (b, h, i, 0)
     kvmap = lambda b, h, i, j: (b, h // cfg.g, _kv_index(cfg, i, j), 0)
@@ -187,11 +202,11 @@ def _fwd_call(cfg: FlashCfg, q, k, v, q_pos):
         ],
         out_specs=[
             pl.BlockSpec((1, 1, cfg.bq, Dv), qmap),
-            pl.BlockSpec((1, 1, cfg.bq), lambda b, h, i, j: (b, h, i)),
+            pl.BlockSpec((1, 1, 1, cfg.bq), _rowmap),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hq, Tq, Dv), q.dtype),
-            jax.ShapeDtypeStruct((B, Hq, Tq), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hq, Tq, Dv), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((B, Hq, 1, Tq), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((cfg.bq, 1), jnp.float32),
@@ -200,7 +215,7 @@ def _fwd_call(cfg: FlashCfg, q, k, v, q_pos):
         ],
         interpret=cfg.interpret,
     )(q_pos, q, k, v)
-    return out, lse
+    return out, lse.reshape(B, Hq, Tq)
 
 
 # ---------------------------------------------------------------------------
@@ -226,11 +241,11 @@ def _dq_kernel(qpos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0, 0].astype(jnp.float32)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * cfg.scale
-        s = jnp.where(_block_mask(cfg, qpos_ref[0], jj), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])          # normalized probs
+        s = jnp.where(_block_mask(cfg, qpos_ref[...].T, jj), s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0, 0].T)                 # normalized probs
         dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
+        ds = p * (dp - delta_ref[0, 0].T)
         acc_ref[...] += lax.dot_general(
             ds, k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * cfg.scale
@@ -266,14 +281,14 @@ def _dkv_kernel(qpos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         do = do_ref[0, 0].astype(jnp.float32)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * cfg.scale
-        s = jnp.where(_block_mask(cfg, qpos_ref[0], kb), s, NEG_INF)
-        p = jnp.exp(s - lse_ref[0, 0][:, None])
+        s = jnp.where(_block_mask(cfg, qpos_ref[...].T, kb), s, NEG_INF)
+        p = jnp.exp(s - lse_ref[0, 0].T)
         dv_acc[...] += lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, 0][:, None])
+        ds = p * (dp - delta_ref[0, 0].T)
         dk_acc[...] += lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * cfg.scale
@@ -289,7 +304,6 @@ def _dq_call(cfg: FlashCfg, q, k, v, q_pos, lse, delta, dout):
     Dv = v.shape[-1]
     qmap = lambda b, h, i, j: (b, h, i, 0)
     kvmap = lambda b, h, i, j: (b, h // cfg.g, _kv_index(cfg, i, j), 0)
-    rowmap = lambda b, h, i, j: (b, h, i)
     return pl.pallas_call(
         functools.partial(_dq_kernel, cfg=cfg),
         grid=(B, Hq, cfg.nq, cfg.nk),
@@ -299,14 +313,14 @@ def _dq_call(cfg: FlashCfg, q, k, v, q_pos, lse, delta, dout):
             pl.BlockSpec((1, 1, cfg.bk, D), kvmap),
             pl.BlockSpec((1, 1, cfg.bk, Dv), kvmap),
             pl.BlockSpec((1, 1, cfg.bq, Dv), qmap),
-            pl.BlockSpec((1, 1, cfg.bq), rowmap),
-            pl.BlockSpec((1, 1, cfg.bq), rowmap),
+            pl.BlockSpec((1, 1, 1, cfg.bq), _rowmap),
+            pl.BlockSpec((1, 1, 1, cfg.bq), _rowmap),
         ],
         out_specs=pl.BlockSpec((1, 1, cfg.bq, D), qmap),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype, vma=vma_of(q)),
         scratch_shapes=[pltpu.VMEM((cfg.bq, D), jnp.float32)],
         interpret=cfg.interpret,
-    )(q_pos, q, k, v, dout, lse, delta)
+    )(q_pos, q, k, v, dout, _lane_rows(lse), _lane_rows(delta))
 
 
 def _dkv_call(cfg: FlashCfg, q, k, v, q_pos, lse, delta, dout):
@@ -314,7 +328,8 @@ def _dkv_call(cfg: FlashCfg, q, k, v, q_pos, lse, delta, dout):
     Hkv, Tk = k.shape[1], k.shape[2]
     Dv = v.shape[-1]
     qmap2 = lambda b, h, kb, gi, qi: (b, h * cfg.g + gi, _q_index(cfg, kb, qi), 0)
-    rowmap2 = lambda b, h, kb, gi, qi: (b, h * cfg.g + gi, _q_index(cfg, kb, qi))
+    rowmap2 = lambda b, h, kb, gi, qi: (b, h * cfg.g + gi, 0,
+                                        _q_index(cfg, kb, qi))
     kvmap2 = lambda b, h, kb, gi, qi: (b, h, kb, 0)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, cfg=cfg),
@@ -326,23 +341,23 @@ def _dkv_call(cfg: FlashCfg, q, k, v, q_pos, lse, delta, dout):
             pl.BlockSpec((1, 1, cfg.bk, D), kvmap2),
             pl.BlockSpec((1, 1, cfg.bk, Dv), kvmap2),
             pl.BlockSpec((1, 1, cfg.bq, Dv), qmap2),
-            pl.BlockSpec((1, 1, cfg.bq), rowmap2),
-            pl.BlockSpec((1, 1, cfg.bq), rowmap2),
+            pl.BlockSpec((1, 1, 1, cfg.bq), rowmap2),
+            pl.BlockSpec((1, 1, 1, cfg.bq), rowmap2),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, cfg.bk, D), kvmap2),
             pl.BlockSpec((1, 1, cfg.bk, Dv), kvmap2),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype, vma=vma_of(k)),
+            jax.ShapeDtypeStruct(v.shape, v.dtype, vma=vma_of(v)),
         ],
         scratch_shapes=[
             pltpu.VMEM((cfg.bk, D), jnp.float32),
             pltpu.VMEM((cfg.bk, Dv), jnp.float32),
         ],
         interpret=cfg.interpret,
-    )(q_pos, q, k, v, dout, lse, delta)
+    )(q_pos, q, k, v, dout, _lane_rows(lse), _lane_rows(delta))
     return dk, dv
 
 
@@ -445,7 +460,10 @@ def _flash_jit(q, k, v, q_pos, *, causal, local_window, q_start,
                    nq=Tqp // bq, nk=Tkp // bk,
                    q_start=(None if q_start is None else int(q_start)),
                    tk_real=Tk, interpret=bool(interpret))
-    out = _flash(cfg, qp, kp, vp, q_pos[None])
+    # one vma for every operand, outside the custom_vjp: each cotangent
+    # then matches its primal and autodiff reduces what the pvary added
+    qp, kp, vp, qpos = unify_vma(qp, kp, vp, q_pos[None])
+    out = _flash(cfg, qp, kp, vp, qpos)
     return out[:, :, :Tq] if Tqp != Tq else out
 
 
@@ -484,8 +502,8 @@ def _step_cfg_pad(q, k, v, q_pos, *, causal, local_window, q_start,
                    nq=Tqp // bq, nk=Tkp // bk,
                    q_start=(None if q_start is None else int(q_start)),
                    tk_real=Tk, interpret=bool(interpret))
-    return (cfg, pad4(q, Tqp), pad4(k, Tkp), pad4(v, Tkp), q_pos[None],
-            Tq, Tqp)
+    return (cfg, *unify_vma(pad4(q, Tqp), pad4(k, Tkp), pad4(v, Tkp),
+                            q_pos[None]), Tq, Tqp)
 
 
 def _pad_rows(x, t):

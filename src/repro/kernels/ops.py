@@ -1,7 +1,7 @@
 """jit'd public wrappers for the Pallas kernels.
 
 On this CPU container the kernels run in interpret mode (the TPU is the
-TARGET, not the runtime); ``repro_kernels_interpret()`` flips automatically
+TARGET, not the runtime); ``interpret_mode()`` flips automatically
 unless a TPU backend is present.  Model code selects the attention data
 path via ``attn_impl`` (RunConfig / ParallelContext):
 
@@ -21,7 +21,7 @@ from .ssd import ssd_intra
 from .tesseract_mm import tesseract_mm, tesseract_mm_stream
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
     return jax.default_backend() != "tpu"
 
 
@@ -36,7 +36,7 @@ def effective_attn_impl(impl: str) -> str:
 
 
 def tesseract_mm_op(a, b, **kw):
-    return tesseract_mm(a, b, interpret=_interpret(), **kw)
+    return tesseract_mm(a, b, interpret=interpret_mode(), **kw)
 
 
 def tesseract_mm_stream_op(a, b, c, **kw):
@@ -47,12 +47,12 @@ def tesseract_mm_stream_op(a, b, c, **kw):
     materializes).  Not yet wired into core/summa.py — the ring schedule
     currently contracts with jnp.einsum, like the fused path does with
     this module's fused kernel."""
-    return tesseract_mm_stream(a, b, c, interpret=_interpret(), **kw)
+    return tesseract_mm_stream(a, b, c, interpret=interpret_mode(), **kw)
 
 
 def flash_attention_op(q, k, v, *, causal=True, **kw):
     """Flash fwd + custom-vjp bwd; q/k/v in [B, H, T, D] kernel layout."""
-    return flash_attention(q, k, v, causal=causal, interpret=_interpret(),
+    return flash_attention(q, k, v, causal=causal, interpret=interpret_mode(),
                            **kw)
 
 
@@ -60,8 +60,8 @@ def paged_attention_op(q, pool_k, pool_v, table, pos, kv_map, **kw):
     """Block-table paged decode attention (no pool gather); see
     kernels/paged_attention.py."""
     return paged_attention(q, pool_k, pool_v, table, pos, kv_map,
-                           interpret=_interpret(), **kw)
+                           interpret=interpret_mode(), **kw)
 
 
 def ssd_intra_op(x, log_a, Bm, Cm, **kw):
-    return ssd_intra(x, log_a, Bm, Cm, interpret=_interpret(), **kw)
+    return ssd_intra(x, log_a, Bm, Cm, interpret=interpret_mode(), **kw)

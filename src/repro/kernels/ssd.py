@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..core.collectives import unify_vma, vma_of
+
 
 def _kernel(x_ref, la_ref, b_ref, c_ref, y_ref, s_ref, *, bh):
     # refs: x [1,1,Q,bh,P]; la [1,1,Q,bh]; b/c [1,1,Q,N]
@@ -62,6 +64,8 @@ def ssd_intra(x, log_a, Bm, Cm, *, bh=None, interpret=False):
     bh = bh or H
     assert H % bh == 0
     grid = (B, nc, H // bh)
+    x, log_a, Bm, Cm = unify_vma(x, log_a, Bm, Cm)
+    vma = vma_of(x)
     y, s = pl.pallas_call(
         functools.partial(_kernel, bh=bh),
         grid=grid,
@@ -76,8 +80,8 @@ def ssd_intra(x, log_a, Bm, Cm, *, bh=None, interpret=False):
             pl.BlockSpec((1, 1, bh, P, N), lambda b, c, h: (b, c, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, nc, Q, H, P), jnp.float32),
-            jax.ShapeDtypeStruct((B, nc, H, P, N), jnp.float32),
+            jax.ShapeDtypeStruct((B, nc, Q, H, P), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct((B, nc, H, P, N), jnp.float32, vma=vma),
         ],
         interpret=interpret,
     )(x, log_a, Bm, Cm)

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.collectives import unify_vma, vma_of
+
 
 DEFAULT_BE = 256
 DEFAULT_BF = 512
@@ -79,6 +81,7 @@ def tesseract_mm(a, b, *, be=DEFAULT_BE, bf=DEFAULT_BF, bg=DEFAULT_BG,
         return (i // nf, i % nf, g)
 
     grid = (E // be, G // bg, n_inner)
+    a, b = unify_vma(a, b)
     out = pl.pallas_call(
         functools.partial(_kernel, n_inner=n_inner),
         grid=grid,
@@ -87,7 +90,7 @@ def tesseract_mm(a, b, *, be=DEFAULT_BE, bf=DEFAULT_BF, bg=DEFAULT_BG,
             pl.BlockSpec((1, bf, bg), b_index),
         ],
         out_specs=pl.BlockSpec((be, bg), lambda e, g, i: (e, g)),
-        out_shape=jax.ShapeDtypeStruct((E, G), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((E, G), jnp.float32, vma=vma_of(a)),
         scratch_shapes=[pltpu.VMEM((be, bg), jnp.float32)],
         interpret=interpret,
     )(a, b)
@@ -135,6 +138,7 @@ def tesseract_mm_stream(a, b, c, *, be=DEFAULT_BE, bf=DEFAULT_BF,
                  [("E", E, "be", be), ("F", F, "bf", bf), ("G", G, "bg", bg)])
     nf = F // bf
     grid = (E // be, G // bg, nf)
+    a, b, c = unify_vma(a, b, c.astype(jnp.float32))
     return pl.pallas_call(
         functools.partial(_stream_kernel, nf=nf),
         grid=grid,
@@ -144,8 +148,8 @@ def tesseract_mm_stream(a, b, c, *, be=DEFAULT_BE, bf=DEFAULT_BF,
             pl.BlockSpec((be, bg), lambda e, g, i: (e, g)),
         ],
         out_specs=pl.BlockSpec((be, bg), lambda e, g, i: (e, g)),
-        out_shape=jax.ShapeDtypeStruct((E, G), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((E, G), jnp.float32, vma=vma_of(a)),
         scratch_shapes=[pltpu.VMEM((be, bg), jnp.float32)],
         input_output_aliases={2: 0},
         interpret=interpret,
-    )(a, b, c.astype(jnp.float32))
+    )(a, b, c)

@@ -93,7 +93,9 @@ def main():
     from ..core.mesh import logical_mesh
     from ..models.registry import build_model, get_arch, get_reduced
     from ..serve import EngineConfig, InferenceEngine, SamplingParams
+    from .compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     arch = get_reduced(args.arch) if args.reduced else get_arch(args.arch)
     run = RunConfig(param_dtype="float32", compute_dtype="float32",
                     loss_chunk=64, q_chunk=32, kv_chunk=32,

@@ -79,9 +79,11 @@ def main():
     from ..configs.base import RunConfig, ShapeSpec
     from ..core.api import ParallelContext
     from ..core.mesh import pipeline_mesh
+    from .compile_cache import enable_compile_cache
     from ..models.registry import build_model, get_arch, get_reduced
     from ..runtime.train_loop import train
 
+    enable_compile_cache()
     arch = get_reduced(args.arch) if args.reduced else get_arch(args.arch)
     run = RunConfig(param_dtype=args.param_dtype,
                     compute_dtype=args.compute_dtype,

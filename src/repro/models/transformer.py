@@ -222,7 +222,7 @@ class DenseLM:
     def _ring_attn(self, q, k, v, ops, ring_axes):
         """Seq-sharded attention: ring/striped flash over ``ring_axes``."""
         from ..core.ring_attention import ring_attention
-        from ..kernels.ops import _interpret, effective_attn_impl
+        from ..kernels.ops import effective_attn_impl
         ctx = self.ctx
         variant = (ctx.train_attn_schedule() if ops.plan.kind == "train"
                    else "ring")  # prefill prompts are never striped
@@ -234,8 +234,7 @@ class DenseLM:
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), axes=ring_axes, variant=variant,
             causal=True, local_window=self.cfg.local_window,
-            impl=effective_attn_impl(self.ctx.attn_impl),
-            interpret=_interpret())
+            impl=effective_attn_impl(self.ctx.attn_impl))
         return out.transpose(0, 2, 1, 3)
 
     def _block_train_attn(self, p, x, ops, full_kv_pos):

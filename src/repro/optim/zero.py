@@ -239,8 +239,8 @@ def zgather(sl, lay: LeafLayout, dtype=None):
     half the gather bytes of the fp32 master)."""
     if dtype is not None:
         sl = sl.astype(dtype)
-    flat = (col.all_gather_inv(sl, lay.zaxes, tiled=True, axis=0)
-            if lay.zn > 1 else sl)
+    # size-1 zaxes gather nothing but still clear the slice's vma there
+    flat = col.all_gather_inv(sl, lay.zaxes, tiled=True, axis=0)
     loc = lay.local_shape
     n = 1
     for d in loc:

@@ -41,8 +41,7 @@ def pipeline_apply(stage_fn, stage_params, x_mb, *, axis: str = "pipe"):
     Returns [M, mb, ...] outputs, valid on the LAST stage (replicated there
     via the caller's reduction; other stages hold garbage).
     """
-    from ..core.collectives import axis_size1
-    S = axis_size1(axis)
+    S = lax.axis_size(axis)
     sid = lax.axis_index(axis)
     M = x_mb.shape[0]
     T = M + S - 1
@@ -229,7 +228,7 @@ def pipeline_1f1b_grads(stage_step, params, a_proto, n_micro: int, *,
     """
     from ..core import collectives as col
 
-    S = col.axis_size1(axis)
+    S = lax.axis_size(axis)
     M = int(n_micro)
     fwd_tbl, bwd_tbl, K, info = schedule or schedule_1f1b(M, S)
     if info["n_micro"] != M or info["n_stages"] != S:
@@ -245,29 +244,27 @@ def pipeline_1f1b_grads(stage_step, params, a_proto, n_micro: int, *,
     fwd_perm = [(i, i + 1) for i in range(S - 1)]
     bwd_perm = [(i + 1, i) for i in range(S - 1)]
 
-    if col.HAS_VMA:
-        # vma discipline: learn every carried leaf's varying axes from one
-        # throwaway forward + zero-cotangent vjp (exact zeros, right vma).
-        a0 = col.pvary(a_proto, (axis,) + tuple(
-            a for a in ("data", "depth", "row", "col")))
-        out0 = stage_step(params, a0, jnp.int32(0))
-        seeds0 = jax.tree.map(
-            lambda o: col.pvary(jnp.zeros(o.shape, o.dtype),
-                                tuple(col.vma_of(o))), out0)
-        _, pull0 = jax.vjp(lambda p, a: stage_step(p, a, jnp.int32(0)),
-                           params, a0)
-        grads0, cot0 = pull0(seeds0)
-        a_store = jnp.zeros((K,) + a_proto.shape, a_proto.dtype) \
-            + (a0 * 0)[None]
-        cot_store = jnp.zeros((K,) + cot0.shape, cot0.dtype) + (cot0 * 0)[None]
-        zero_ld = col.pvary(jnp.float32(0), ("data", axis))
-        loss_acc, cnt_acc = zero_ld, zero_ld
-    else:
-        a_store = jnp.zeros((K,) + a_proto.shape, a_proto.dtype)
-        cot_store = jnp.zeros((K,) + a_proto.shape, a_proto.dtype)
-        grads0 = jax.tree.map(jnp.zeros_like, params)
-        loss_acc = jnp.float32(0)
-        cnt_acc = jnp.float32(0)
+    # vma discipline: learn every carried leaf's varying axes from one
+    # throwaway forward + zero-cotangent vjp (exact zeros, right vma).
+    mesh_axes = jax.sharding.get_abstract_mesh().axis_names
+    a0 = col.pvary(a_proto, tuple(
+        a for a in (axis, "data", "depth", "row", "col") if a in mesh_axes))
+    out0 = stage_step(params, a0, jnp.int32(0))
+    seeds0 = jax.tree.map(
+        lambda o: col.pvary(jnp.zeros(o.shape, o.dtype),
+                            tuple(col.vma_of(o))), out0)
+    _, pull0 = jax.vjp(lambda p, a: stage_step(p, a, jnp.int32(0)),
+                       params, a0)
+    grads0, cot0 = pull0(seeds0)
+    # a tick's contribution is selected by this stage's schedule slot, so
+    # the accumulated grads vary over the pipe axis for every leaf
+    grads0 = jax.tree.map(lambda g: col.pvary(g, (axis,)), grads0)
+    a_store = jnp.zeros((K,) + a_proto.shape, a_proto.dtype) \
+        + (a0 * 0)[None]
+    cot_store = jnp.zeros((K,) + cot0.shape, cot0.dtype) + (cot0 * 0)[None]
+    zero_ld = col.pvary(jnp.float32(0), tuple(
+        a for a in ("data", axis) if a in mesh_axes))
+    loss_acc, cnt_acc = zero_ld, zero_ld
 
     seed_val = jnp.float32(loss_seed)
 

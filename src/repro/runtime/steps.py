@@ -19,12 +19,12 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs.base import RunConfig, ShapeSpec
 from ..core.api import LOGICAL_AXES, ParallelContext
-from ..core.collectives import pvary, grad_sync, axis_size, shard_map
+from ..core.collectives import pvary, grad_sync, axis_size, psum_v
 from ..core.ops import Plan, make_ops
 from ..optim import adamw
 
@@ -218,7 +218,7 @@ def zero_optimizer_step(params, opt_state, grads, *, layouts, is_tess,
         val = jnp.sum(sl.astype(jnp.float32) ** 2) / rep
         return pvary(val, rem)
     sq = sum(jax.tree.leaves(jax.tree.map(slice_sq, g_sl, layouts, specs)))
-    gnorm = jnp.sqrt(lax.psum(sq, gnorm_axes))
+    gnorm = jnp.sqrt(psum_v(sq, gnorm_axes))
     scale = jnp.minimum(1.0, run.grad_clip / (gnorm + 1e-6))
     g_sl = jax.tree.map(lambda g: g * scale, g_sl)
 
@@ -307,7 +307,6 @@ def build_train_step(model, mesh, shape: ShapeSpec, *, accum_steps: int = 1,
                else jax.tree.map(lambda _: False, specs))
 
     rep_tree = jax.tree.map(lambda s: rep_factor(ctx, s), specs)
-    from ..core import collectives as col_mod
     from ..optim import zero as zopt
 
     use_zero = run.zero_enabled
@@ -318,10 +317,8 @@ def build_train_step(model, mesh, shape: ShapeSpec, *, accum_steps: int = 1,
                 "optimizer='lamb' with ZeRO-1 is not wired: the trust "
                 "ratios need unsharded per-leaf norms")
         def _leaf_norm(x):
-            # global L2 of a sharded leaf.  On pre-vma jax psum_v reduces
-            # replicated axes too (x the rep factor) — it cancels in LAMB's
-            # ||p||/||u|| trust ratio because p and u share a layout.
-            from ..core.collectives import psum_v
+            # global L2 of a sharded leaf: psum_v reduces only the axes
+            # the leaf is split over
             return jnp.sqrt(psum_v(jnp.sum(x.astype(jnp.float32) ** 2),
                                    maxes))
         update_fn = partial(adamw.lamb_update, norm_fn=_leaf_norm)
@@ -374,8 +371,14 @@ def build_train_step(model, mesh, shape: ShapeSpec, *, accum_steps: int = 1,
             out = model.loss(pv, mb, ops)
             return out * ls if ls != 1.0 else out
 
+        # ZeRO-1 differentiates w.r.t. params made varying over their zaxes
+        # OUTSIDE the vjp: the grads then arrive as unreduced partial sums
+        # there (no autodiff psum) and zreduce_scatter reduces them.
+        dparams = (jax.tree.map(
+            lambda x, lay, t: pvary(x, () if t else lay.zaxes),
+            params, layouts, is_tess) if use_zero else params)
         if accum_steps == 1:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            loss, grads = jax.value_and_grad(loss_fn)(dparams, batch)
         else:
             # microbatch gradient accumulation: split every batch leaf's
             # local batch dim into accum_steps slices and scan, so only one
@@ -387,11 +390,11 @@ def build_train_step(model, mesh, shape: ShapeSpec, *, accum_steps: int = 1,
 
             def micro(carry, mb):
                 c_loss, c_grads = carry
-                l, g = jax.value_and_grad(loss_fn)(params, mb)
+                l, g = jax.value_and_grad(loss_fn)(dparams, mb)
                 return (c_loss + l, jax.tree.map(jnp.add, c_grads, g)), None
 
             init = (jnp.float32(0),
-                    jax.tree.map(lambda p: p * 0, params))
+                    jax.tree.map(lambda p: p * 0, dparams))
             (loss, grads), _ = lax.scan(micro, init, mbs)
             loss = loss / accum_steps
             grads = jax.tree.map(lambda g: g / accum_steps, grads)
@@ -401,15 +404,6 @@ def build_train_step(model, mesh, shape: ShapeSpec, *, accum_steps: int = 1,
             grads = jax.tree.map(lambda g: g / ls, grads)
         if fscale is not None:
             grads = jax.tree.map(lambda g: g * fscale, grads)
-
-        if not col_mod.HAS_VMA:
-            # Pre-vma jax seeds ALL p replicated copies of the loss scalar
-            # (psum transposes to psum), so value_and_grad returns exactly
-            # p x the true gradient for every leaf; vma jax seeds the one
-            # invariant scalar and needs no correction.
-            p_rep = ctx.data * ctx.seq * ctx.depth * ctx.rows * ctx.cols
-            if p_rep > 1:
-                grads = jax.tree.map(lambda g: g / p_rep, grads)
 
         lr = adamw.cosine_lr(opt_state["step"], base_lr=run.lr,
                              warmup=100, total=10000)
@@ -426,7 +420,7 @@ def build_train_step(model, mesh, shape: ShapeSpec, *, accum_steps: int = 1,
                 return pvary(val, replicated_axes(s, maxes))
             sq = sum(jax.tree.leaves(jax.tree.map(leaf_sq, grads, rep_tree,
                                                   specs)))
-            gnorm = jnp.sqrt(lax.psum(sq, maxes))
+            gnorm = jnp.sqrt(psum_v(sq, maxes))
             scale = jnp.minimum(1.0, run.grad_clip / (gnorm + 1e-6))
             grads = jax.tree.map(lambda g: g * scale, grads)
             new_params, new_state = update_fn(
@@ -543,7 +537,6 @@ def _build_pipeline_train_step(model, mesh, shape: ShapeSpec,
     in PP, gradient accumulation IS more microbatches through the same
     flush, which also shrinks the bubble.
     """
-    from ..core import collectives as col_mod
     from .pipeline import pipeline_1f1b_grads
 
     ctx: ParallelContext = model.ctx
@@ -660,22 +653,21 @@ def _build_pipeline_train_step(model, mesh, shape: ShapeSpec,
             ls, cnt = model.pipe_loss_sums(p, y, lab, ops)
             return y, ls, cnt
 
+        # the stage vjps differentiate w.r.t. params made varying over every
+        # axis their grads are reduced on below (red() psums, the ZeRO-1
+        # reduce_scatter), so they hand back unreduced partial sums there
+        vparams = jax.tree.map(
+            lambda x, ax, t, z: pvary(x, ax if t else ax + z), params,
+            red_axes, is_tess,
+            jax.tree.map(lambda lay: lay.zaxes, layouts) if use_zero
+            else jax.tree.map(lambda _: (), params))
         a_proto = jnp.zeros((mb_can, S_seq, h_loc), cdt)
         loss_sum, cnt_sum, grads, _ = pipeline_1f1b_grads(
-            stage_step, params, a_proto, M, axis="pipe", loss_seed=seed,
+            stage_step, vparams, a_proto, M, axis="pipe", loss_seed=seed,
             schedule=sched)
         loss_sum = lax.psum(loss_sum, (ctx.axis_data, "pipe"))
         cnt = lax.psum(cnt_sum, (ctx.axis_data, "pipe"))
         loss = loss_sum / jnp.maximum(cnt, 1.0)
-
-        if not col_mod.HAS_VMA:
-            # Pre-vma jax: every model-group member seeds its own replicated
-            # copy of the last stage's loss sums (psum transposes to psum),
-            # so grads arrive scaled by the model-group size.  The data axis
-            # is NOT included here: its reduction happens outside the vjp.
-            corr = ctx.depth * ctx.rows * ctx.cols
-            if corr > 1:
-                grads = jax.tree.map(lambda g: g / corr, grads)
 
         def red(g, ax):
             if not ax:
@@ -706,7 +698,7 @@ def _build_pipeline_train_step(model, mesh, shape: ShapeSpec,
                                                         else ("pipe",)))
             sq = sum(jax.tree.leaves(jax.tree.map(
                 leaf_sq, grads, rep_tree, specs, pipe_sharded)))
-            gnorm = jnp.sqrt(lax.psum(sq, LOGICAL_AXES + ("pipe",)))
+            gnorm = jnp.sqrt(psum_v(sq, LOGICAL_AXES + ("pipe",)))
             scale = jnp.minimum(1.0, run.grad_clip / (gnorm + 1e-6))
             grads = jax.tree.map(lambda g: g * scale, grads)
             new_params, new_state = adamw.adamw_update(

@@ -42,6 +42,7 @@ from .stragglers import StragglerMonitor
 @dataclass
 class TrainResult:
     losses: list = field(default_factory=list)
+    grad_norms: list = field(default_factory=list)
     restarts: int = 0
     last_step: int = -1
     step_times: list = field(default_factory=list)
@@ -210,6 +211,7 @@ def train(model, mesh, shape, *, steps: int, ckpt_dir=None, ckpt_every: int = 50
                     if not np.isfinite(loss):
                         raise FloatingPointError(f"non-finite loss at {step}")
                     result.losses.append(loss)
+                    result.grad_norms.append(float(metrics["grad_norm"]))
                     result.last_step = step
                     if log_every and step % log_every == 0:
                         print(f"step {step} loss {loss:.4f} "

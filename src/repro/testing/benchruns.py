@@ -89,7 +89,8 @@ def matmul_schedules():
     from jax import lax
     from jax.sharding import PartitionSpec as P
     from repro.core.api import ParallelContext
-    from repro.core.collectives import grad_sync, shard_map
+    from jax import shard_map
+    from repro.core.collectives import grad_sync
     from repro.core.mesh import logical_mesh
     from repro.core.summa import tesseract_matmul
 

@@ -1,14 +1,10 @@
-"""Multi-device correctness: each test spawns a subprocess with 8 fake CPU
-devices (XLA_FLAGS is never set in this process — smoke tests see 1 device,
-per the harness requirement)."""
-import os
-import pathlib
-import subprocess
-import sys
-
+"""Multi-device correctness, dense models: each test spawns a subprocess
+with 8 fake CPU devices (tests/mdcheck_run.py).  The other mdchecks live
+in tests/test_multidevice_{families,attention,serve}.py; chaos_train /
+chaos_serve in tests/test_chaos.py."""
 import pytest
 
-SRC = str(pathlib.Path(__file__).resolve().parents[1] / "src")
+from mdcheck_run import run_mdcheck
 
 CHECKS = [
     "summa_exact",
@@ -16,32 +12,10 @@ CHECKS = [
     "inop_matches_deferred",
     "decode_parity",
     "prefill_parity",
-    "smollm_padding",
-    "moe_parity",
-    "moe_decode",
-    "families_parity",
-    "families_serve",
-    "ring_train_parity",
     "zero1_parity",
-    "zero1_elastic",
-    "moe_local_layout",
-    "serve_engine",
-    "engine_elastic",
-    "attn_impl_parity",
-    "ring_attention",
-    "pipeline_parity",
-    "train_elastic_accum",
-    # chaos_train / chaos_serve live in tests/test_chaos.py (same
-    # subprocess harness) next to the rest of the fault-injection suite
 ]
 
 
 @pytest.mark.parametrize("check", CHECKS)
 def test_mdcheck(check):
-    env = dict(os.environ, PYTHONPATH=SRC)
-    env.pop("XLA_FLAGS", None)
-    r = subprocess.run(
-        [sys.executable, "-m", "repro.testing.mdchecks", check],
-        env=env, capture_output=True, text=True, timeout=1800)
-    assert r.returncode == 0, f"{check} failed:\n{r.stdout[-3000:]}\n{r.stderr[-3000:]}"
-    assert f"PASS" in r.stdout
+    run_mdcheck(check)

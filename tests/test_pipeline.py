@@ -12,7 +12,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.core.collectives import shard_map
+from jax import shard_map
 from repro.core.mesh import make_mesh
 from repro.runtime.pipeline import pipeline_apply
 
@@ -104,7 +104,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
-from repro.core.collectives import shard_map
+from jax import shard_map
 from repro.core.mesh import make_mesh
 from repro.runtime.pipeline import pipeline_1f1b_grads
 

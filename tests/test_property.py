@@ -113,7 +113,7 @@ def test_ce_loss_chunk_invariance(chunk, seed):
             # ce_loss leaves the sums varying over data; reduce like the
             # models do
             return jax.lax.psum(ls, "data") / jax.lax.psum(cnt, "data")
-        from repro.core.collectives import shard_map
+        from jax import shard_map
         return shard_map(f, mesh=mesh,
                              in_specs=(P(None, None, None), P(None, None),
                                        P(None, None)),

@@ -1,7 +1,7 @@
 """Ring/striped flash attention statics (DESIGN.md §15): the striped
 permutation, the per-step mask oracle, causal load balance, the ppermute
 comm model, and the schedule/config validation surface.  The multi-device
-numerics live in the ``ring_attention`` mdcheck (tests/test_multidevice.py
+numerics live in the ``ring_attention`` mdcheck (tests/test_multidevice_attention.py
 runs it in a subprocess)."""
 import numpy as np
 import pytest
@@ -222,7 +222,7 @@ def test_ring_attention_rejects_striped_window():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.core.collectives import shard_map
+    from jax import shard_map
     from repro.core.ring_attention import ring_attention
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("s",))

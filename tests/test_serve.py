@@ -1,5 +1,5 @@
 """Serve subsystem tests (single device; multi-device engine parity lives in
-tests/test_multidevice.py via the serve_engine / engine_elastic mdchecks)."""
+tests/test_multidevice_serve.py via the serve_engine / engine_elastic mdchecks)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

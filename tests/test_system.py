@@ -1,6 +1,6 @@
 """End-to-end behaviour tests: train -> checkpoint -> restore -> serve on
 the public API (single device; multi-device parity lives in
-tests/test_multidevice.py)."""
+tests/test_multidevice*.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np

@@ -7,8 +7,8 @@ bit-stability, and the adamw m/v downcast guard.
 
 Multi-device parity (q x dp x master grid, pipeline mesh, elastic
 re-partitioning) runs through repro.testing.mdchecks subprocesses —
-``zero1_parity`` / ``zero1_elastic`` in tests/test_multidevice.py on 8 fake
-devices; here only the q=2 x dp=4 cell that needs 16 fake devices.
+``zero1_parity`` / ``zero1_elastic`` in tests/test_multidevice*.py on 8
+fake devices; here only the q=2 x dp=4 cell that needs 16 fake devices.
 """
 import os
 import pathlib
