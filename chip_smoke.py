@@ -124,13 +124,14 @@ def serve_phase(arch, devices, layout, *, attn_impl="auto",
     # the serve launcher's precision: fp32 weights and compute
     model, mesh = build(arch, devices, layout, attn_impl=attn_impl,
                         param_dtype="float32", compute_dtype="float32")
-    params = model.init(jax.random.PRNGKey(SEED))
     max_seq = max(prompt_lens) + new_tokens
     blocks_per_req = -(-max_seq // BLOCK_SIZE)
-    engine = InferenceEngine(model, mesh, params, EngineConfig(
-        n_slots=N_SLOTS, block_size=BLOCK_SIZE,
-        num_blocks=N_SLOTS * blocks_per_req + 8 * len(devices),
-        max_seq_len=blocks_per_req * BLOCK_SIZE))
+    # no reference to the init kept: the engine holds only its placed copy
+    engine = InferenceEngine(
+        model, mesh, model.init(jax.random.PRNGKey(SEED)), EngineConfig(
+            n_slots=N_SLOTS, block_size=BLOCK_SIZE,
+            num_blocks=N_SLOTS * blocks_per_req + 8 * len(devices),
+            max_seq_len=blocks_per_req * BLOCK_SIZE))
     tag = f"serve {layout[0]} {layout[1:]} attn={engine.attn_impl}"
     rng = np.random.RandomState(SEED)
     vocab = model.cfg.vocab_size

@@ -109,7 +109,6 @@ def main():
                           attn_impl=run.attn_impl)
     mesh = logical_mesh(ctx)
     model = build_model(arch.model, ctx, run)
-    params = model.init(jax.random.PRNGKey(0))
 
     draft_model = draft_params = None
     if args.draft_config:
@@ -123,12 +122,13 @@ def main():
         draft_model = build_model(dcfg, ctx, run)
         draft_params = draft_model.init(jax.random.PRNGKey(7))
 
-    engine = InferenceEngine(model, mesh, params, EngineConfig(
-        n_slots=args.n_slots, block_size=args.block_size,
-        num_blocks=args.num_blocks, max_seq_len=args.max_seq_len,
-        max_waiting=args.max_waiting, prefix_cache=args.prefix_cache,
-        prefill_chunk=args.prefill_chunk, spec_k=args.spec_k,
-        spec_mode=args.spec_mode),
+    engine = InferenceEngine(
+        model, mesh, model.init(jax.random.PRNGKey(0)), EngineConfig(
+            n_slots=args.n_slots, block_size=args.block_size,
+            num_blocks=args.num_blocks, max_seq_len=args.max_seq_len,
+            max_waiting=args.max_waiting, prefix_cache=args.prefix_cache,
+            prefill_chunk=args.prefill_chunk, spec_k=args.spec_k,
+            spec_mode=args.spec_mode),
         draft_model=draft_model, draft_params=draft_params)
 
     plens = [int(x) for x in args.prompt_lens.split(",")]

@@ -24,9 +24,12 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..configs.base import ShapeSpec
+from ..core.ops import make_ops
 from ..runtime import faults as faults_mod
 from ..runtime.steps import (build_chunk_prefill_step, build_page_copy,
                              build_paged_decode_step, build_paged_reshard,
@@ -146,7 +149,7 @@ class InferenceEngine:
     def __init__(self, model, mesh, params, cfg: EngineConfig,
                  injector=None, clock=None, draft_model=None,
                  draft_params=None):
-        self.model, self.mesh, self.params, self.cfg = model, mesh, params, cfg
+        self.model, self.mesh, self.cfg = model, mesh, cfg
         # injectable wall clock: deadline/TTFT tests drive a fake clock
         self.clock = clock or time.perf_counter
         self.injector = injector if injector is not None else \
@@ -161,9 +164,18 @@ class InferenceEngine:
         self.draft_model = draft_model
         self._draft_params_host = None
         if draft_model is not None and cfg.spec_k > 0:
-            import jax
             self._draft_params_host = jax.tree.map(np.asarray, draft_params)
         self._build()
+        self.params = self._place_params(params)
+
+    def _place_params(self, params):
+        """Params laid out as the steps take them, so that no step call
+        reshards them (an array built on one device would otherwise stay
+        there whole, beside its sharded copies)."""
+        specs = self.model.specs(make_ops(self.model.ctx, self.plan))
+        shardings = jax.tree.map(lambda sp: NamedSharding(self.mesh, sp),
+                                 specs, is_leaf=lambda x: isinstance(x, P))
+        return jax.tree.map(jax.device_put, params, shardings)
 
     # ---------------------------------------------------------------- build
     def _build(self):
@@ -894,13 +906,9 @@ class InferenceEngine:
         copies every running request's resident blocks into its new group's
         partition, and recompiles the serve steps.  Waiting requests and all
         request state survive untouched."""
-        import jax
         from ..core.mesh import logical_mesh
         from ..models.registry import build_model
         from ..runtime.elastic import replan
-
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        from ..core.ops import make_ops
 
         rp = replan(n_devices, self.model.ctx,
                     global_batch=self.cfg.n_slots)
@@ -925,11 +933,7 @@ class InferenceEngine:
         self.mesh = logical_mesh(rp.ctx, jax.devices()[:rp.n_used])
         self._build()    # stats/requests survive (guarded init in _build)
 
-        # re-place params on the shrunken mesh
-        specs = self.model.specs(make_ops(rp.ctx, self.plan))
-        shardings = jax.tree.map(lambda sp: NamedSharding(self.mesh, sp),
-                                 specs, is_leaf=lambda x: isinstance(x, P))
-        self.params = jax.tree.map(jax.device_put, params_np, shardings)
+        self.params = self._place_params(params_np)   # the shrunken mesh
 
         # carry scheduler state over; reallocate pages in the new groups.
         # The admit clock must carry too: carried residents keep their old
