@@ -1,0 +1,13 @@
+"""mlp_ms.train: device milliseconds a traced step spends in the ``mlp`` scope,
+the MLP sublayer: ln2, the gate, up and down matmuls, SiLU and the residual:
+the ops (not the loops around them) whose innermost layer scope it is
+(``chip/scopes.py``), inside the window, averaged over chips. Moves
+train_tokens_per_s."""
+from chip import scopes
+
+
+def read(run):
+    s = scopes.for_run(run)
+    if s is None:
+        return None
+    return 1e3 * s.scope_seconds("mlp") / run.cell.traffic["trace_steps"]

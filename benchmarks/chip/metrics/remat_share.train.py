@@ -1,0 +1,13 @@
+"""remat_share.train: share of the device's busy time in the traced window
+spent on ops that remat recomputes in the backward pass (their name stack
+holds ``rematted_computation``; ``chip/scopes.py``), averaged over chips.
+Moves train_tokens_per_s."""
+from chip import scopes
+
+
+def read(run):
+    s = scopes.for_run(run)
+    busy = run.trace.busy_s() if run.trace is not None else 0.0
+    if s is None or busy <= 0:
+        return None
+    return 100.0 * s.remat_seconds() / busy

@@ -336,6 +336,9 @@ class TesseractOps:
         xc = xf.reshape(n_chunks, c_loc, xf.shape[-1])
         lc = lab.reshape(n_chunks, c_loc)
         mc = lm.reshape(n_chunks, c_loc)
+        # made here, not in the checkpointed chunk: remat's partial eval
+        # would hoist it out of the chunk loop without the caller's scope
+        vmask = (v_off + jnp.arange(v_loc)) < vocab_real
 
         @jax.checkpoint
         def chunk_loss(xw, chunk):
@@ -346,7 +349,6 @@ class TesseractOps:
             xg = col.all_gather_inv(xg, ctx.axis_col, tiled=True, axis=xg.ndim - 1)
             lg = col.all_gather_cat(l_chunk, gather_axes, axis=0)
             logits = _f32_einsum("ch,vh->cv", xg, xw, out_dtype=jnp.float32)
-            vmask = (v_off + jnp.arange(v_loc)) < vocab_real
             logits = jnp.where(vmask[None, :], logits, -jnp.inf)
             m_loc = jnp.max(logits, axis=-1)
             m = lax.pmax(lax.stop_gradient(m_loc), model_axes)
@@ -656,6 +658,9 @@ class MegatronOps:
         xc = xf.reshape(n_chunks, c_loc, xf.shape[-1])
         lc = lab.reshape(n_chunks, c_loc)
         mc = lm.reshape(n_chunks, c_loc)
+        # made here, not in the checkpointed chunk: remat's partial eval
+        # would hoist it out of the chunk loop without the caller's scope
+        vmask = (v_off + jnp.arange(v_loc)) < vocab_real
 
         @jax.checkpoint
         def chunk_loss(xw, chunk):
@@ -667,7 +672,6 @@ class MegatronOps:
                 x_chunk = col.all_gather_cat(x_chunk, ctx.axis_col, axis=0)
                 l_chunk = col.all_gather_cat(l_chunk, ctx.axis_col, axis=0)
             logits = _f32_einsum("ch,vh->cv", x_chunk, xw, out_dtype=jnp.float32)
-            vmask = (v_off + jnp.arange(v_loc)) < vocab_real
             logits = jnp.where(vmask[None, :], logits, -jnp.inf)
             m_l = jnp.max(logits, -1)
             m = col.pmax_v(lax.stop_gradient(m_l), self.tp_axes)

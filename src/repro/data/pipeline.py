@@ -71,12 +71,14 @@ class Prefetcher:
                 host = self.stream.batch(step, train=self.train)
                 dev = {k: jax.device_put(v, self.shardings[k])
                        for k, v in host.items() if k in self.shardings}
-                try:
-                    self.q.put((step, dev), timeout=1.0)
-                except queue.Full:
-                    if self._stop.is_set():
-                        return
-                    continue
+                # a full queue keeps this step's batch: it is made once
+                while True:
+                    try:
+                        self.q.put((step, dev), timeout=1.0)
+                        break
+                    except queue.Full:
+                        if self._stop.is_set():
+                            return
                 step += 1
         except BaseException as e:  # propagate to the consumer, don't die mute
             self._exc = e

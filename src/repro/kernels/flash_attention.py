@@ -193,6 +193,7 @@ def _fwd_call(cfg: FlashCfg, q, k, v, q_pos):
     kvmap = lambda b, h, i, j: (b, h // cfg.g, _kv_index(cfg, i, j), 0)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, cfg=cfg),
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, cfg.bq), lambda b, h, i, j: (0, i)),
@@ -306,6 +307,7 @@ def _dq_call(cfg: FlashCfg, q, k, v, q_pos, lse, delta, dout):
     kvmap = lambda b, h, i, j: (b, h // cfg.g, _kv_index(cfg, i, j), 0)
     return pl.pallas_call(
         functools.partial(_dq_kernel, cfg=cfg),
+        name="flash_dq",
         grid=(B, Hq, cfg.nq, cfg.nk),
         in_specs=[
             pl.BlockSpec((1, cfg.bq), lambda b, h, i, j: (0, i)),
@@ -333,6 +335,7 @@ def _dkv_call(cfg: FlashCfg, q, k, v, q_pos, lse, delta, dout):
     kvmap2 = lambda b, h, kb, gi, qi: (b, h, kb, 0)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, cfg=cfg),
+        name="flash_dkv",
         grid=(B, Hkv, cfg.nk, cfg.g, cfg.nq),
         in_specs=[
             pl.BlockSpec((1, cfg.bq),

@@ -162,6 +162,7 @@ def paged_attention(q, pool_k, pool_v, table, pos, kv_map, *,
     )
     call = pl.pallas_call(
         functools.partial(_kernel, cfg=cfg),
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dv), q.dtype, vma=vma),
         interpret=cfg.interpret,

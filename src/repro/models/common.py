@@ -14,6 +14,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+# The train step's layers, as ``jax.named_scope`` names them in every op's
+# name stack (HLO ``metadata={op_name=...}``): an op belongs to the
+# innermost of these its name stack holds (``roofline.hlo.scope_of``).
+LAYER_SCOPES = ("embed", "blocks", "attn", "mlp", "head_loss", "optimizer")
+EMBED, BLOCKS, ATTN, MLP, HEAD_LOSS, OPTIMIZER = LAYER_SCOPES
+
 
 # ---------------------------------------------------------------------------
 # init helpers

@@ -202,6 +202,12 @@ class DenseLM:
             h = act(ops.linear_up(xg, p["w_up"], p.get("b_up")))
         return ops.linear_down(h, p["w_down"], p.get("b_down"))
 
+    @jax.named_scope(cm.MLP)
+    def _mlp_sublayer(self, p, x, ops):
+        """ln2, the MLP and its residual."""
+        h2 = self._norm(ops, x, p["ln2"], p.get("ln2b"))
+        return x + self._mlp(p, h2, ops)
+
     # -------------------------------------------------------------- train
     def _ring_axes(self, ops):
         """Mesh axes to stream K/V around, or None for the local schedule.
@@ -237,6 +243,7 @@ class DenseLM:
             impl=effective_attn_impl(self.ctx.attn_impl))
         return out.transpose(0, 2, 1, 3)
 
+    @jax.named_scope(cm.ATTN)
     def _block_train_attn(self, p, x, ops, full_kv_pos):
         """Attention sublayer (residual included); returns (x, (k, v) local
         seq-slices for prefill caching)."""
@@ -277,20 +284,19 @@ class DenseLM:
 
     def _block_train(self, p, x, ops, full_kv_pos):
         x, _ = self._block_train_attn(p, x, ops, full_kv_pos)
-        h2 = self._norm(ops, x, p["ln2"], p.get("ln2b"))
-        x = x + self._mlp(p, h2, ops)
-        return x
+        return self._mlp_sublayer(p, x, ops)
 
     def _run_blocks(self, params, x, ops, block_fn):
         body = maybe_remat(
             lambda xx, bp: (block_fn(bp, xx), None), self.run)
-        if self.run.scan_blocks:
-            x, _ = lax.scan(body, x, params["blocks"])
-        else:
-            L = jax.tree.leaves(params["blocks"])[0].shape[0]
-            for i in range(L):
-                bp = jax.tree.map(lambda a: a[i], params["blocks"])
-                x, _ = body(x, bp)
+        with jax.named_scope(cm.BLOCKS):
+            if self.run.scan_blocks:
+                x, _ = lax.scan(body, x, params["blocks"])
+            else:
+                L = jax.tree.leaves(params["blocks"])[0].shape[0]
+                for i in range(L):
+                    bp = jax.tree.map(lambda a: a[i], params["blocks"])
+                    x, _ = body(x, bp)
         return x
 
     # --- pipeline stage API (runtime/steps pipelined train path) ---
@@ -305,6 +311,7 @@ class DenseLM:
     # capacity-factor MoE routing (token grouping is layout dependent).
     supports_seq_shard = True
 
+    @jax.named_scope(cm.EMBED)
     def pipe_embed(self, params, tokens, ops):
         """Host-layout ids -> canonical activation (stage-0 entry)."""
         return ops.embed(tokens, params["embed"]).astype(self.cdt)
@@ -321,6 +328,7 @@ class DenseLM:
             params, x, ops,
             lambda bp, xx: self._block_train(cast(bp), xx, ops, full_kv_pos))
 
+    @jax.named_scope(cm.HEAD_LOSS)
     def pipe_loss_sums(self, params, x, labels, ops, label_mask=None):
         """Final norm + chunked CE -> local (loss_sum, count) (last stage)."""
         x = self._norm(ops, x, params["ln_f"], params.get("ln_fb"))
@@ -406,18 +414,18 @@ class DenseLM:
         ``pos_mask`` / ``kv_map`` are position-only values hoisted out of
         the layer scan by decode_paged."""
         cfg = self.cfg
-        h = self._norm(ops, x, p["ln1"], p.get("ln1b"))
-        q, k, v = self._qkv(p, h, ops, pos[:, None])
-        pool_l = cm.paged_update(pool_l, table, pos, k, v, idx=idx)
-        out = cm.paged_attention(q[:, 0], pool_l["k"], pool_l["v"], table,
-                                 pos, kv_map=kv_map,
-                                 local_window=cfg.local_window,
-                                 pos_mask=pos_mask,
-                                 impl=self.ctx.attn_impl)
-        x = x + self._attn_out(p, out[:, None], ops, self._head_mask(ops))
-        h2 = self._norm(ops, x, p["ln2"], p.get("ln2b"))
-        x = x + self._mlp(p, h2, ops)
-        return x, pool_l
+        with jax.named_scope(cm.ATTN):
+            h = self._norm(ops, x, p["ln1"], p.get("ln1b"))
+            q, k, v = self._qkv(p, h, ops, pos[:, None])
+            pool_l = cm.paged_update(pool_l, table, pos, k, v, idx=idx)
+            out = cm.paged_attention(q[:, 0], pool_l["k"], pool_l["v"],
+                                     table, pos, kv_map=kv_map,
+                                     local_window=cfg.local_window,
+                                     pos_mask=pos_mask,
+                                     impl=self.ctx.attn_impl)
+            x = x + self._attn_out(p, out[:, None], ops,
+                                   self._head_mask(ops))
+        return self._mlp_sublayer(p, x, ops), pool_l
 
     def decode_paged(self, params, pool, table, ids, pos, ops):
         """One continuous-batching serve step against the paged block pool.
@@ -457,17 +465,16 @@ class DenseLM:
         against the request's pages (update-then-attend, so a COW donor's
         stale tail is overwritten before it could ever be visible — and the
         causal mask hides whatever this chunk didn't reach)."""
-        cfg = self.cfg
-        h = self._norm(ops, x, p["ln1"], p.get("ln1b"))
-        q, k, v = self._qkv(p, h, ops, positions)
-        pool_l = cm.paged_update_chunk(pool_l, table, positions, k, v,
-                                       valid, idx=idx)
-        kg, vg = cm.paged_gather(pool_l["k"], pool_l["v"], table, kv_map)
-        out = cm.chunk_attention(q, kg, vg, mask=mask)
-        x = x + self._attn_out(p, out, ops, self._head_mask(ops))
-        h2 = self._norm(ops, x, p["ln2"], p.get("ln2b"))
-        x = x + self._mlp(p, h2, ops)
-        return x, pool_l
+        with jax.named_scope(cm.ATTN):
+            h = self._norm(ops, x, p["ln1"], p.get("ln1b"))
+            q, k, v = self._qkv(p, h, ops, positions)
+            pool_l = cm.paged_update_chunk(pool_l, table, positions, k, v,
+                                           valid, idx=idx)
+            kg, vg = cm.paged_gather(pool_l["k"], pool_l["v"], table,
+                                     kv_map)
+            out = cm.chunk_attention(q, kg, vg, mask=mask)
+            x = x + self._attn_out(p, out, ops, self._head_mask(ops))
+        return self._mlp_sublayer(p, x, ops), pool_l
 
     def _chunk_trunk(self, params, pool, table, ids, pos, lens, ops):
         """Shared chunk body for prefill_chunk_paged / verify_chunk_paged:
@@ -564,9 +571,7 @@ class DenseLM:
         """Like _block_train but also emits this block's seq-local K/V
         (prefill cache stays sequence-sharded — see prefill_cache_specs)."""
         x, kv = self._block_train_attn(p, x, ops, full_kv_pos)
-        h2 = self._norm(ops, x, p["ln2"], p.get("ln2b"))
-        x = x + self._mlp(p, h2, ops)
-        return x, kv
+        return self._mlp_sublayer(p, x, ops), kv
 
     def batch_extras(self, shape):
         """Extra (modality) inputs: {name: (ShapeDtypeStruct, host_spec)}."""
@@ -612,27 +617,26 @@ class DenseLM:
     def _block_decode_attnonly(self, p, x, cache_l, pos, ops, *,
                                pos_mask=None, kv_map=None):
         cfg = self.cfg
-        h = self._norm(ops, x, p["ln1"], p.get("ln1b"))
-        positions = jnp.full((1,), pos, jnp.int32)
-        q, k, v = self._qkv(p, h, ops, positions)
-        cache_l = cm.cache_update(cache_l, k, v, pos)
-        if kv_map is None and not self.kv_shard:
-            kv_map = self._kv_map(ops)
-        out = cm.decode_attention(q[:, 0], cache_l["k"], cache_l["v"],
-                                  cur_pos=pos, kv_map=kv_map,
-                                  local_window=cfg.local_window,
-                                  pos_mask=pos_mask,
-                                  impl=self.ctx.attn_impl)
-        out = out[:, None]                      # [B, 1, H, D]
-        x = x + self._attn_out(p, out, ops, self._head_mask(ops))
+        with jax.named_scope(cm.ATTN):
+            h = self._norm(ops, x, p["ln1"], p.get("ln1b"))
+            positions = jnp.full((1,), pos, jnp.int32)
+            q, k, v = self._qkv(p, h, ops, positions)
+            cache_l = cm.cache_update(cache_l, k, v, pos)
+            if kv_map is None and not self.kv_shard:
+                kv_map = self._kv_map(ops)
+            out = cm.decode_attention(q[:, 0], cache_l["k"], cache_l["v"],
+                                      cur_pos=pos, kv_map=kv_map,
+                                      local_window=cfg.local_window,
+                                      pos_mask=pos_mask,
+                                      impl=self.ctx.attn_impl)
+            out = out[:, None]                      # [B, 1, H, D]
+            x = x + self._attn_out(p, out, ops, self._head_mask(ops))
         return x, cache_l
 
     def _block_decode(self, p, x, cache_l, pos, ops, **hoisted):
         x, cache_l = self._block_decode_attnonly(p, x, cache_l, pos, ops,
                                                  **hoisted)
-        h2 = self._norm(ops, x, p["ln2"], p.get("ln2b"))
-        x = x + self._mlp(p, h2, ops)
-        return x, cache_l
+        return self._mlp_sublayer(p, x, ops), cache_l
 
     def decode(self, params, cache, ids, pos, ops):
         """One serve step: ids [B', 1] host-layout; returns (new_ids, cache)."""

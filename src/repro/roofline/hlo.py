@@ -15,12 +15,17 @@ while-loop trip counts from their condition computations, and aggregates
 
 multiplying every called computation by its trip count.  Elementwise flops
 are ignored (these workloads are dot-dominated; noted in EXPERIMENTS.md).
+
+``instructions`` lists the module's instructions with their op_name (the
+JAX name stack), and ``scope_of`` reads an op's layer scope from it.
 """
 from __future__ import annotations
 
 import re
 from collections import defaultdict
 from dataclasses import dataclass, field
+
+from ..models.common import LAYER_SCOPES
 
 DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s4": 1, "u4": 1,
@@ -34,10 +39,12 @@ COLLECTIVE_KINDS = ("all-gather", "all-reduce", "reduce-scatter",
 
 _SHAPE = re.compile(r"\b(\w+?)\[([\d,]*)\]")
 _COMP_HEADER = re.compile(r"^(ENTRY\s+)?%?([\w.\-_]+)\s*\(.*\{\s*$")
-# output type: tuple "(...)" (may contain /*index=N*/ comments; no nested
-# parens in HLO types) or a scalar/array type with optional layout braces
+# output type: a tuple "(...)" (may contain /*index=N*/ comments, TPU tiled
+# layouts such as {1,0:T(8,128)(2,1)} and a nested tuple) or an array type
+# with its layout (no spaces)
+_TUPLE = r"\((?:[^()]|\((?:[^()]|\([^()]*\))*\))*\)"
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-_]+)\s*=\s*"
-                    r"(\([^)]*\)|[\w\[\],{}]+)\s+([a-z][\w\-]*)\(")
+                    r"(" + _TUPLE + r"|\S+)\s+([a-z][\w\-]*)\(")
 _OPERANDS = re.compile(r"%([\w.\-_]+)")
 _CONTRACT = re.compile(r"lhs_contracting_dims=\{([\d,]*)\}")
 _CONST = re.compile(r"constant\((\d+)\)")
@@ -102,6 +109,114 @@ def split_computations(text: str):
         else:
             buf.append(line)
     return comps
+
+
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+# attributes naming computations that run as ops of their own (loop bodies,
+# branches, calls); fusions and reductions name computations that do not
+_RUN_CALLS = re.compile(
+    r"\b(?:body|condition|true_computation|false_computation)=%?([\w.\-]+)"
+    r"|\bbranch_computations=\{([^}]*)\}")
+
+
+@dataclass
+class Instr:
+    name: str          # the instruction: ``fusion.3`` of ``%fusion.3 = ...``
+    opcode: str
+    op_name: str       # its metadata's op_name (the JAX name stack), or ""
+    fused: tuple       # a fusion's: the op_names of the ops fused into it
+    runs: bool         # a top-level op: in a computation that runs as ops
+
+
+def instructions(text: str) -> list:
+    """Every instruction of an HLO module's text with its op_name.  The
+    device trace shows the ``runs`` ones: those of the entry computation,
+    of loop bodies and conditions, of branches and of called computations,
+    not the inside of a fusion or a reduction's region."""
+    parsed = {}
+    for cname, lines in split_computations(text).items():
+        rows = []
+        for line in lines:
+            mo = _INSTR.match(line)
+            if mo:
+                on = _OP_NAME.search(line)
+                rows.append((mo.group(1), mo.group(3),
+                             on.group(1) if on else "", line))
+        parsed[cname] = rows
+
+    def fused_names(line, seen=()):
+        out = []
+        for c in re.findall(r"\bcalls=%?([\w.\-]+)", line):
+            for _n, op, o, inner in parsed.get(c, ()) if c not in seen else ():
+                out += [o] if o else []
+                if op == "fusion":
+                    out += fused_names(inner, seen + (c,))
+        return out
+
+    m = re.search(r"^ENTRY\s+%?([\w.\-_]+)", text, re.M)
+    run, todo = set(), [m.group(1)] if m else []
+    while todo:
+        c = todo.pop()
+        if c in run or c not in parsed:
+            continue
+        run.add(c)
+        for _n, op, _o, line in parsed[c]:
+            for a, b in _RUN_CALLS.findall(line):
+                todo += [a] if a else [x.strip().lstrip("%")
+                                       for x in b.split(",")]
+            if op in ("call", "async-start"):
+                todo += re.findall(r"\b(?:to_apply|calls)=%?([\w.\-]+)",
+                                   line)
+    return [Instr(n, op, o,
+                  tuple(fused_names(line)) if op == "fusion" else (),
+                  c in run)
+            for c, rows in parsed.items() for n, op, o, line in rows]
+
+
+# --- an op's layer, from its name stack ---------------------------------
+# the scope jax.checkpoint gives the ops it recomputes in the backward pass
+REMAT_SCOPE = "rematted_computation"
+
+
+def _name_stack(op_name: str) -> list:
+    """The scopes of an op_name, transform wrappers taken off:
+    ``jit(f)/transpose(jvp(attn))/dot_general`` -> [f, attn, dot_general]."""
+    out = []
+    for seg in op_name.split(";", 1)[0].split("/"):
+        while seg.endswith(")") and "(" in seg:
+            seg = seg[seg.index("(") + 1:-1]
+        out.append(seg)
+    return out
+
+
+def layer_scope(op_name: str):
+    """The innermost of the model's ``LAYER_SCOPES`` in an op's name
+    stack, or None."""
+    for seg in reversed(_name_stack(op_name)):
+        if seg in LAYER_SCOPES:
+            return seg
+    return None
+
+
+def rematted(op_name: str) -> bool:
+    """True for an op that remat recomputes in the backward pass."""
+    return REMAT_SCOPE in _name_stack(op_name)
+
+
+def scope_of(op_name: str, fused=()) -> tuple:
+    """(layer scope or None, rematted) of an op: from its own op_name where
+    that names a layer, else from those of the ops fused into it (a
+    multi-output fusion's tuple root has none; the SPMD partitioner names
+    some ops after themselves) by what most of them that name a layer say,
+    the first on a tie; (None, False) where none does."""
+    if layer_scope(op_name) is not None:
+        return layer_scope(op_name), rematted(op_name)
+    votes = {}
+    for o in fused:
+        k = (layer_scope(o), rematted(o))
+        if k[0] is not None:
+            votes[k] = votes.get(k, 0) + 1
+    return max(votes, key=votes.get) if votes else (None, False)
 
 
 def _group_size(line: str, default: int) -> int:

@@ -26,6 +26,7 @@ from ..configs.base import RunConfig, ShapeSpec
 from ..core.api import LOGICAL_AXES, ParallelContext
 from ..core.collectives import pvary, grad_sync, axis_size, psum_v
 from ..core.ops import Plan, make_ops
+from ..models.common import OPTIMIZER
 from ..optim import adamw
 
 
@@ -405,35 +406,36 @@ def build_train_step(model, mesh, shape: ShapeSpec, *, accum_steps: int = 1,
         if fscale is not None:
             grads = jax.tree.map(lambda g: g * fscale, grads)
 
-        lr = adamw.cosine_lr(opt_state["step"], base_lr=run.lr,
-                             warmup=100, total=10000)
-        if use_zero:
-            new_params, new_state, gnorm = zero_optimizer_step(
-                params, opt_state, grads, layouts=layouts, is_tess=is_tess,
-                specs=specs, axis_sizes=axis_sizes, run=run,
-                update_fn=update_fn, lr=lr, gnorm_axes=maxes,
-                mesh_axes=maxes)
-        else:
-            # --- global grad-norm clip (layout aware) ---
-            def leaf_sq(g, rep, s):
-                val = jnp.sum(g.astype(jnp.float32) ** 2) / rep
-                return pvary(val, replicated_axes(s, maxes))
-            sq = sum(jax.tree.leaves(jax.tree.map(leaf_sq, grads, rep_tree,
-                                                  specs)))
-            gnorm = jnp.sqrt(psum_v(sq, maxes))
-            scale = jnp.minimum(1.0, run.grad_clip / (gnorm + 1e-6))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-            new_params, new_state = update_fn(
-                params, grads, opt_state, lr=lr,
-                weight_decay=run.weight_decay)
-        # non-finite update guard: any NaN/Inf grad poisons gnorm (sum of
-        # squares), so one scalar predicate covers every leaf; the select
-        # keeps params/opt bit-identical on a poisoned step
-        finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-        new_params = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
-                                  new_params, params)
-        new_state = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
-                                 new_state, opt_state)
+        with jax.named_scope(OPTIMIZER):
+            lr = adamw.cosine_lr(opt_state["step"], base_lr=run.lr,
+                                 warmup=100, total=10000)
+            if use_zero:
+                new_params, new_state, gnorm = zero_optimizer_step(
+                    params, opt_state, grads, layouts=layouts,
+                    is_tess=is_tess, specs=specs, axis_sizes=axis_sizes,
+                    run=run, update_fn=update_fn, lr=lr, gnorm_axes=maxes,
+                    mesh_axes=maxes)
+            else:
+                # --- global grad-norm clip (layout aware) ---
+                def leaf_sq(g, rep, s):
+                    val = jnp.sum(g.astype(jnp.float32) ** 2) / rep
+                    return pvary(val, replicated_axes(s, maxes))
+                sq = sum(jax.tree.leaves(jax.tree.map(leaf_sq, grads,
+                                                      rep_tree, specs)))
+                gnorm = jnp.sqrt(psum_v(sq, maxes))
+                scale = jnp.minimum(1.0, run.grad_clip / (gnorm + 1e-6))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+                new_params, new_state = update_fn(
+                    params, grads, opt_state, lr=lr,
+                    weight_decay=run.weight_decay)
+            # non-finite update guard: any NaN/Inf grad poisons gnorm (sum
+            # of squares), so one scalar predicate covers every leaf; the
+            # select keeps params/opt bit-identical on a poisoned step
+            finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+            new_params = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
+                                      new_params, params)
+            new_state = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
+                                     new_state, opt_state)
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr,
                    "skipped": 1.0 - finite.astype(jnp.float32)}
         return new_params, new_state, metrics
@@ -682,34 +684,35 @@ def _build_pipeline_train_step(model, mesh, shape: ShapeSpec,
         if fscale is not None:
             grads = jax.tree.map(lambda g: g * fscale, grads)
 
-        lr = adamw.cosine_lr(opt_state["step"], base_lr=run.lr,
-                             warmup=100, total=10000)
-        if use_zero:
-            new_params, new_state, gnorm = zero_optimizer_step(
-                params, opt_state, grads, layouts=layouts, is_tess=is_tess,
-                specs=specs, axis_sizes=axis_sizes, run=run,
-                update_fn=adamw.adamw_update, lr=lr,
-                gnorm_axes=LOGICAL_AXES + ("pipe",))
-        else:
-            # --- global grad-norm clip (layout + stage aware) ---
-            def leaf_sq(g, rep, s, psh):
-                val = jnp.sum(g.astype(jnp.float32) ** 2) / rep
-                return pvary(val, replicated_axes(s) + (() if psh
-                                                        else ("pipe",)))
-            sq = sum(jax.tree.leaves(jax.tree.map(
-                leaf_sq, grads, rep_tree, specs, pipe_sharded)))
-            gnorm = jnp.sqrt(psum_v(sq, LOGICAL_AXES + ("pipe",)))
-            scale = jnp.minimum(1.0, run.grad_clip / (gnorm + 1e-6))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-            new_params, new_state = adamw.adamw_update(
-                params, grads, opt_state, lr=lr,
-                weight_decay=run.weight_decay)
-        # non-finite update guard (same contract as the flat-mesh step)
-        finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
-        new_params = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
-                                  new_params, params)
-        new_state = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
-                                 new_state, opt_state)
+        with jax.named_scope(OPTIMIZER):
+            lr = adamw.cosine_lr(opt_state["step"], base_lr=run.lr,
+                                 warmup=100, total=10000)
+            if use_zero:
+                new_params, new_state, gnorm = zero_optimizer_step(
+                    params, opt_state, grads, layouts=layouts,
+                    is_tess=is_tess, specs=specs, axis_sizes=axis_sizes,
+                    run=run, update_fn=adamw.adamw_update, lr=lr,
+                    gnorm_axes=LOGICAL_AXES + ("pipe",))
+            else:
+                # --- global grad-norm clip (layout + stage aware) ---
+                def leaf_sq(g, rep, s, psh):
+                    val = jnp.sum(g.astype(jnp.float32) ** 2) / rep
+                    return pvary(val, replicated_axes(s) + (() if psh
+                                                            else ("pipe",)))
+                sq = sum(jax.tree.leaves(jax.tree.map(
+                    leaf_sq, grads, rep_tree, specs, pipe_sharded)))
+                gnorm = jnp.sqrt(psum_v(sq, LOGICAL_AXES + ("pipe",)))
+                scale = jnp.minimum(1.0, run.grad_clip / (gnorm + 1e-6))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+                new_params, new_state = adamw.adamw_update(
+                    params, grads, opt_state, lr=lr,
+                    weight_decay=run.weight_decay)
+            # non-finite update guard (same contract as the flat-mesh step)
+            finite = jnp.isfinite(loss) & jnp.isfinite(gnorm)
+            new_params = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
+                                      new_params, params)
+            new_state = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
+                                     new_state, opt_state)
         metrics = {"loss": loss, "grad_norm": gnorm, "lr": lr,
                    "skipped": 1.0 - finite.astype(jnp.float32)}
         return new_params, new_state, metrics

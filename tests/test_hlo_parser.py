@@ -9,6 +9,8 @@ loops, and dots living inside fused computations.
 """
 import textwrap
 
+import pytest
+
 from repro.roofline.hlo import (analyze_hlo, collective_stats,
                                 split_computations, total_collective_bytes)
 
@@ -152,3 +154,13 @@ def test_while_trip_count_defaults_to_one_without_condition_constant():
         }
     """)
     assert analyze_hlo(mod, n_devices=1)["flops"] == 2 * 4 * 2
+
+
+@pytest.mark.parametrize("mod", [FUSION, NESTED_WHILE, TUPLE_COLLECTIVES],
+                         ids=["fusion", "nested_while", "tuple_collectives"])
+def test_tpu_tiled_layouts_parse_as_plain_ones(mod):
+    # the TPU compiler prints tiles inside the layout, parentheses and all
+    tpu = (mod.replace("{1,0}", "{1,0:T(8,128)}")
+           .replace("s32[] ", "s32[]{:T(128)} "))
+    assert tpu != mod
+    assert analyze_hlo(tpu, n_devices=8) == analyze_hlo(mod, n_devices=8)

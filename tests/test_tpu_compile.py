@@ -70,8 +70,11 @@ def test_flash_fwd_bwd_compiles(arch, T, one_chip, no_compile_cache):
     c = _compile(step, [((1, Hq, T, D), jnp.bfloat16),
                         ((1, Hkv, T, D), jnp.bfloat16),
                         ((1, Hkv, T, D), jnp.bfloat16)], one_chip)
-    # forward, dQ and dK/dV passes are three kernels
-    assert c.as_text().count("tpu_custom_call") >= 3
+    # forward, dQ and dK/dV passes are three kernels, each by its name
+    text = c.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert f"%{name}" in text
 
 
 @pytest.mark.parametrize("arch", sorted(WIDTHS))
@@ -89,3 +92,57 @@ def test_paged_decode_compiles(arch, one_chip, no_compile_cache):
                         ((P, bs, Hkv, D), jnp.bfloat16),
                         ((B, nb), jnp.int32), ((B,), jnp.int32)], one_chip)
     assert "tpu_custom_call" in c.as_text()
+    assert "%paged_decode" in c.as_text()
+
+
+# a tiny DenseLM train step as the one-chip cell runs it (fp32 params,
+# bf16 compute, full remat, the Pallas kernels), compiled on one chip and
+# on a [2,2,1] grid; the ops the device trace shows carry the layer scopes
+WORK_OPS = ("fusion", "dot", "convolution", "custom-call", "reduce",
+            "scatter")
+
+
+def _train_step_hlo(devices, rows, cols, monkeypatch):
+    import repro.kernels.ops as kops
+    from repro.configs.base import RunConfig, ShapeSpec
+    from repro.core.api import ParallelContext
+    from repro.core.mesh import logical_mesh
+    from repro.models.registry import build_model, get_reduced
+    from repro.runtime.steps import build_train_step
+    monkeypatch.setattr(kops, "interpret_mode", lambda: False)
+    run = RunConfig(param_dtype="float32", compute_dtype="bfloat16",
+                    remat="full", attn_impl="pallas")
+    ctx = ParallelContext(mode="tesseract", data=1, depth=1, rows=rows,
+                          cols=cols, attn_impl="pallas")
+    model = build_model(get_reduced("smollm-360m").model, ctx, run)
+    b = build_train_step(model, logical_mesh(ctx, devices),
+                         ShapeSpec("t", seq_len=256, global_batch=4,
+                                   kind="train"))
+    return b.fn.lower(*b.abstract_inputs).compile().as_text()
+
+
+@pytest.mark.parametrize("rows,cols", [(1, 1), (2, 2)],
+                         ids=["one_chip", "grid_2x2x1"])
+def test_train_step_ops_carry_layer_scopes(rows, cols, topo, monkeypatch,
+                                           no_compile_cache):
+    import re
+    from repro.models.common import LAYER_SCOPES
+    from repro.roofline.hlo import instructions, scope_of
+    text = _train_step_hlo(topo.devices[:rows * cols], rows, cols,
+                           monkeypatch)
+    ops = [i for i in instructions(text) if i.runs]
+    # buffers the compiler allocates for itself: no work, no op_name
+    alloc = set(re.findall(r"%([\w.\-]+) = \S+ custom-call\(\), "
+                           r'custom_call_target="AllocateBuffer"', text))
+    work = [i for i in ops if i.opcode in WORK_OPS and i.name not in alloc]
+    scoped = {i.name: scope_of(i.op_name, i.fused) for i in work}
+    assert [n for n, s in scoped.items() if s[0] is None] == []
+    assert {s for s, _ in scoped.values()} == set(LAYER_SCOPES)
+    assert any(r for _, r in scoped.values())        # remat recomputes
+    # the kernels by name, in attention; full remat runs the forward twice
+    kern = {n: scoped[n] for n in scoped if re.match(r"flash_", n)}
+    assert {n.split(".")[0] for n in kern} == {"flash_fwd", "flash_dq",
+                                                 "flash_dkv"}
+    assert {s for s, _ in kern.values()} == {"attn"}
+    fwd = sorted(r for n, (_, r) in kern.items() if n.startswith("flash_fwd"))
+    assert fwd == [False, True]
