@@ -40,13 +40,14 @@ DOUBLE_BUFFER = 2               # pallas pipelines blocks in/out
 SUBLANES, LANES = 8, 128
 
 
-def find_pallas_eqns(closed_jaxpr) -> list:
-    """Every pallas_call eqn reachable from a closed jaxpr."""
+def find_eqns(jaxpr, primitive: str = "pallas_call") -> list:
+    """Every ``primitive`` eqn reachable from a (closed) jaxpr, through the
+    sub-jaxprs in eqn params (a pallas_call's kernel body included)."""
     out = []
 
     def walk(jaxpr):
         for eqn in jaxpr.eqns:
-            if eqn.primitive.name == "pallas_call":
+            if eqn.primitive.name == primitive:
                 out.append(eqn)
             for v in eqn.params.values():
                 for vi in (v if isinstance(v, (tuple, list)) else (v,)):
@@ -55,7 +56,7 @@ def find_pallas_eqns(closed_jaxpr) -> list:
                     elif isinstance(vi, jcore.Jaxpr):
                         walk(vi)
 
-    walk(closed_jaxpr.jaxpr)
+    walk(getattr(jaxpr, "jaxpr", jaxpr))
     return out
 
 
@@ -138,7 +139,7 @@ def lint_grid_mapping(gm, kernel: str = "") -> tuple:
 def lint_closed_jaxpr(closed_jaxpr, kernel: str = "") -> tuple:
     """(findings, {pallas_call_i: stats}) over one traced entry."""
     findings, stats = [], {}
-    for i, eqn in enumerate(find_pallas_eqns(closed_jaxpr)):
+    for i, eqn in enumerate(find_eqns(closed_jaxpr)):
         f, s = lint_grid_mapping(eqn.params["grid_mapping"],
                                  f"{kernel}/pallas_call_{i}")
         findings += f

@@ -26,6 +26,14 @@ heads never materialize expanded K/V.  Non-tile-divisible Tq/Tk are
 zero-padded and masked (cols >= Tk are dead), so any shape runs.
 Fully-masked rows (e.g. a ``local_window`` that excludes every key)
 produce EXACT zero output rows, matching models/common.blockwise_attention.
+
+MXU operands (``_mxu_dot``): q, k, v and dO enter every product in the
+input dtype, accumulated in f32 — a bf16 x bf16 product is exact in f32,
+so S = QK^T and dP = dO V^T lose nothing to the native bf16 MXU path.
+P and dS are f32; against bf16 V, K, dO or Q they go as a bf16 hi/lo
+pair (two bf16 products, ~16 mantissa bits of P/dS kept) rather than as
+an f32 operand the MXU takes in several passes.  f32 inputs keep one f32
+product each.
 """
 from __future__ import annotations
 
@@ -130,6 +138,24 @@ def _block_mask(cfg: FlashCfg, rows, jj):
     return mask
 
 
+def _mxu_dot(a, b, dims):
+    """``dot_general(a, b, dims)`` accumulated in f32, without upcasting
+    ``b`` (a block read in the input dtype).  An f32 ``a`` (P, dS) against
+    a narrower ``b`` goes as a hi/lo pair in b's dtype."""
+    dot = functools.partial(lax.dot_general, dimension_numbers=dims,
+                            preferred_element_type=jnp.float32)
+    if a.dtype == jnp.float32 and jnp.finfo(b.dtype).bits < 32:
+        hi = a.astype(b.dtype)
+        lo = (a - hi.astype(jnp.float32)).astype(b.dtype)
+        return dot(hi, b) + dot(lo, b)
+    return dot(a, b)
+
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
 def _rowmap(b, h, i, *_):
     """Index map of a lane-dense [B, H, 1, T] row-statistics block."""
     return (b, h, 0, i)
@@ -159,19 +185,14 @@ def _fwd_kernel(qpos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(lo + j < hi)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * cfg.scale
+        s = _mxu_dot(q_ref[0, 0], k_ref[0, 0], _NT) * cfg.scale
         s = jnp.where(_block_mask(cfg, qpos_ref[...].T, jj), s, NEG_INF)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - _msafe(m_new))                    # masked entries -> 0
         corr = jnp.exp(_msafe(m_prev) - _msafe(m_new))
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, 0].astype(jnp.float32)
-        acc_ref[...] = acc_ref[...] * corr + lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * corr + _mxu_dot(p, v_ref[0, 0], _NN)
         m_ref[...] = m_new
 
     @pl.when(j == cfg.nk - 1)
@@ -236,20 +257,13 @@ def _dq_kernel(qpos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(lo + j < hi)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * cfg.scale
+        k, do = k_ref[0, 0], do_ref[0, 0]
+        s = _mxu_dot(q_ref[0, 0], k, _NT) * cfg.scale
         s = jnp.where(_block_mask(cfg, qpos_ref[...].T, jj), s, NEG_INF)
         p = jnp.exp(s - lse_ref[0, 0].T)                 # normalized probs
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        dp = _mxu_dot(do, v_ref[0, 0], _NT)
         ds = p * (dp - delta_ref[0, 0].T)
-        acc_ref[...] += lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * cfg.scale
+        acc_ref[...] += _mxu_dot(ds, k, _NN) * cfg.scale
 
     @pl.when(j == cfg.nk - 1)
     def _done():
@@ -276,23 +290,14 @@ def _dkv_kernel(qpos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(lo + qi < hi)
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * cfg.scale
+        q, do = q_ref[0, 0], do_ref[0, 0]
+        s = _mxu_dot(q, k_ref[0, 0], _NT) * cfg.scale
         s = jnp.where(_block_mask(cfg, qpos_ref[...].T, kb), s, NEG_INF)
         p = jnp.exp(s - lse_ref[0, 0].T)
-        dv_acc[...] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+        dv_acc[...] += _mxu_dot(p, do, _TN)
+        dp = _mxu_dot(do, v_ref[0, 0], _NT)
         ds = p * (dp - delta_ref[0, 0].T)
-        dk_acc[...] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * cfg.scale
+        dk_acc[...] += _mxu_dot(ds, q, _TN) * cfg.scale
 
     @pl.when((gi == cfg.g - 1) & (qi == cfg.nq - 1))
     def _done():

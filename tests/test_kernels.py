@@ -146,6 +146,67 @@ def test_flash_bwd_through_fully_masked_rows():
         np.testing.assert_array_equal(np.asarray(g), 0.0)
 
 
+@pytest.mark.parametrize("dtype,counts", [
+    ("bfloat16", {"flash_fwd": 3, "flash_dq": 4, "flash_dkv": 6}),
+    ("float32", {"flash_fwd": 2, "flash_dq": 3, "flash_dkv": 4}),
+])
+def test_flash_mxu_operands_stay_in_input_dtype(dtype, counts):
+    """Every product in the three kernels takes its operands in the input
+    dtype: bf16 inputs reach the MXU as bf16 (P and dS as a hi/lo pair,
+    one extra product each), f32 inputs as one f32 product.  Traced only."""
+    from repro.analysis.pallas_lint import find_eqns
+    sds = jax.ShapeDtypeStruct
+    q = sds((1, 2, 256, 64), dtype)
+    kv = sds((1, 1, 256, 64), dtype)
+
+    def loss(q, k, v):
+        out = flash_attention_op(q, k, v, causal=True, bq=128, bk=128)
+        return jnp.sum(out.astype(jnp.float32))
+
+    tr = jax.jit(jax.value_and_grad(loss, (0, 1, 2))).trace(q, kv, kv)
+    dots = {e.params["name"]: [tuple(str(v.aval.dtype) for v in d.invars)
+                               for d in find_eqns(e.params["jaxpr"],
+                                                  "dot_general")]
+            for e in find_eqns(tr.jaxpr)}
+    assert {n: len(d) for n, d in dots.items()} == counts
+    for name, pairs in dots.items():
+        assert set(pairs) == {(dtype, dtype)}, (name, pairs)
+
+
+def test_flash_bf16_precision_pinned():
+    """bf16 GQA fwd+bwd against an f32 HIGHEST-precision oracle: relative
+    errors within 5% of those of the kernels with f32 MXU operands.  P and
+    dS rounded once to bf16 (no lo half) read 17-23% higher."""
+    B, Hq, Hkv, T, D = 2, 3, 1, 512, 64
+    ks = jax.random.split(KEY, 4)
+    q, k, v = (jax.random.normal(kk, (B, h, T, D), jnp.float32)
+               .astype(jnp.bfloat16)
+               for kk, h in zip(ks, (Hq, Hkv, Hkv)))
+    ct = jax.random.normal(ks[3], (B, Hq, T, D), jnp.float32)
+
+    def oracle(q, k, v):
+        k, v = jnp.repeat(k, Hq // Hkv, 1), jnp.repeat(v, Hq // Hkv, 1)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       precision="highest") / np.sqrt(D)
+        s = jnp.where(jnp.tril(jnp.ones((T, T), bool)), s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v,
+                          precision="highest")
+
+    got, vjp = jax.vjp(lambda a, b, c: flash_attention_op(
+        a, b, c, causal=True, bq=128, bk=128), q, k, v)
+    want, vjp_ref = jax.vjp(
+        oracle, *(x.astype(jnp.float32) for x in (q, k, v)))
+
+    def rel(a, b):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+    errs = [rel(got, want)] + [
+        rel(a, b) for a, b in zip(vjp(ct.astype(jnp.bfloat16)), vjp_ref(ct))]
+    pinned = [1.5916e-3, 2.433e-3, 2.441e-3, 2.381e-3]    # out, dq, dk, dv
+    np.testing.assert_allclose(errs, pinned, rtol=0.05)
+
+
 # ---------------------------------------------------------------------------
 # paged decode kernel vs the jnp gather path
 # ---------------------------------------------------------------------------
