@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..models.common import TP_COMM
 from .api import ParallelContext
 from .collectives import all_gather_inv
 
@@ -194,26 +195,35 @@ def _rc(ctx):
     return (ctx.axis_row, ctx.axis_col)
 
 
+# The collectives of every schedule run under ``jax.named_scope(TP_COMM)``,
+# inside the scope of the layer that calls the matmul, so that a trace tells
+# the SUMMA matmul's communication from the rest of its layer.
+@jax.named_scope(TP_COMM)
 def _skew_a(ctx, x):
     return x if ctx.q == 1 else lax.ppermute(x, _rc(ctx), _perm_skew_a(ctx.q))
 
 
+@jax.named_scope(TP_COMM)
 def _unskew_a(ctx, x):
     return x if ctx.q == 1 else lax.ppermute(x, _rc(ctx), _perm_unskew_a(ctx.q))
 
 
+@jax.named_scope(TP_COMM)
 def _skew_w(ctx, x):
     return x if ctx.q == 1 else lax.ppermute(x, _rc(ctx), _perm_skew_w(ctx.q))
 
 
+@jax.named_scope(TP_COMM)
 def _unskew_w(ctx, x):
     return x if ctx.q == 1 else lax.ppermute(x, _rc(ctx), _perm_unskew_w(ctx.q))
 
 
+@jax.named_scope(TP_COMM)
 def _shift_col(ctx, x):
     return x if ctx.q == 1 else lax.ppermute(x, ctx.axis_col, _perm_shift(ctx.q))
 
 
+@jax.named_scope(TP_COMM)
 def _shift_row(ctx, x):
     return x if ctx.q == 1 else lax.ppermute(x, ctx.axis_row, _perm_shift(ctx.q))
 
@@ -287,14 +297,27 @@ def tesseract_matmul(ctx: ParallelContext, a, w):
     return c
 
 
+@jax.named_scope(TP_COMM)
 def _gather_a(ctx, a):
     # A_{h,t} for all t: the q row-broadcasts of Algorithm 3, fused.
     return all_gather_inv(a, ctx.axis_col)          # [q, ..., E_loc, F_loc]
 
 
+@jax.named_scope(TP_COMM)
 def _gather_w(ctx, w):
     # W_{t,j} for all t: the q column-broadcasts of Algorithm 3, fused.
     return all_gather_inv(w, ctx.axis_row)          # [q, F_loc, G_loc]
+
+
+@jax.named_scope(TP_COMM)
+def _scatter(x, axis):
+    # sum the [q, ...] partial stack over ``axis``, keep this device's block
+    return lax.psum_scatter(x, axis, scatter_dimension=0, tiled=False)
+
+
+@jax.named_scope(TP_COMM)
+def _reduce_dgrad(ctx, dw):
+    return lax.psum(dw, _dgrad_axes(ctx))
 
 
 def _tess_fwd(ctx: ParallelContext, a, w):
@@ -321,14 +344,12 @@ def _tess_bwd(ctx: ParallelContext, res, dc):
         # dA_{h,t} = sum_j dC_{h,j} W_{t,j}^T   (paper's C = A * B^T form)
         da_part = _einsum("...eg,tfg->t...ef", dc, wg, ctx=ctx,
                           out_dtype=dc.dtype)
-        da = lax.psum_scatter(da_part, ctx.axis_col, scatter_dimension=0,
-                              tiled=False)
+        da = _scatter(da_part, ctx.axis_col)
         # dW_{t,j} = sum_h A_{h,t}^T dC_{h,j}   (paper's C = A^T * B form)
         rs_dtype = jnp.bfloat16 if ctx.dgrad_rs_bf16 else jnp.float32
         dw_part = _einsum("t...ef,...eg->tfg", ag, dc, ctx=ctx,
                           out_dtype=rs_dtype)
-        dw = lax.psum_scatter(dw_part, ctx.axis_row, scatter_dimension=0,
-                              tiled=False)
+        dw = _scatter(dw_part, ctx.axis_row)
     if ctx.reduce_dgrad_in_op:
         # Paper-faithful per-op reduction: "all_reduce after the computation
         # of B' on processors with same row and column but different depth"
@@ -336,7 +357,7 @@ def _tess_bwd(ctx: ParallelContext, res, dc):
         # reduction happens once per step at the pvary boundary instead.
         # Params are replicated over the seq axis too, so the in-op reduce
         # must cover it (in-op-reduced weights skip the step-level pvary).
-        dw = lax.psum(dw, _dgrad_axes(ctx))
+        dw = _reduce_dgrad(ctx, dw)
     return da, dw.astype(wr.dtype)  # wr dtype == w dtype in both cache modes
 
 
@@ -360,8 +381,8 @@ def tesseract_matmul_experts(ctx: ParallelContext, a, w):
 def _tess_exp_fwd(ctx, a, w):
     if effective_schedule(ctx, a.shape[-2]) == "ring":
         return _ring_fwd(ctx, a, w, "nef,nfg->neg"), (a, w)
-    ag = all_gather_inv(a, ctx.axis_col)      # [q, N, T, F_loc]
-    wg = all_gather_inv(w, ctx.axis_row)      # [q, N, F_loc, G_loc]
+    ag = _gather_a(ctx, a)                    # [q, N, T, F_loc]
+    wg = _gather_w(ctx, w)                    # [q, N, F_loc, G_loc]
     c = _einsum("tnef,tnfg->neg", ag, wg, ctx=ctx, out_dtype=a.dtype)
     res = (ag if ctx.cache_act_gather else a,
            wg if ctx.cache_weight_gather else w)
@@ -374,13 +395,13 @@ def _tess_exp_bwd(ctx, res, dc):
         da, dw = _ring_bwd(ctx, ar, wr, dc,
                            "neg,nfg->nef", "nef,neg->nfg")
         return da, dw.astype(wr.dtype)
-    ag = ar if ctx.cache_act_gather else all_gather_inv(ar, ctx.axis_col)
-    wg = wr if ctx.cache_weight_gather else all_gather_inv(wr, ctx.axis_row)
+    ag = ar if ctx.cache_act_gather else _gather_a(ctx, ar)
+    wg = wr if ctx.cache_weight_gather else _gather_w(ctx, wr)
     da_part = _einsum("neg,tnfg->tnef", dc, wg, ctx=ctx, out_dtype=dc.dtype)
-    da = lax.psum_scatter(da_part, ctx.axis_col, scatter_dimension=0, tiled=False)
+    da = _scatter(da_part, ctx.axis_col)
     rs_dtype = jnp.bfloat16 if ctx.dgrad_rs_bf16 else jnp.float32
     dw_part = _einsum("tnef,neg->tnfg", ag, dc, ctx=ctx, out_dtype=rs_dtype)
-    dw = lax.psum_scatter(dw_part, ctx.axis_row, scatter_dimension=0, tiled=False)
+    dw = _scatter(dw_part, ctx.axis_row)
     return da, dw.astype(wr.dtype)
 
 
@@ -443,9 +464,9 @@ def _tess_wt_fwd(ctx, a, w):
         return _ring_wt_fwd(ctx, a, w), (a, w)
     # C_{h,t} = sum_j A_{h,j} W_{t,j}^T : broadcast W within its column,
     # compute, then reduce partial C within the row (paper 3.1, C = A*B^T).
-    wg = all_gather_inv(w, ctx.axis_row)            # [q(t), G_loc, F_loc]
+    wg = _gather_w(ctx, w)                          # [q(t), G_loc, F_loc]
     part = _einsum("...ef,tgf->t...eg", a, wg, ctx=ctx, out_dtype=a.dtype)
-    c = lax.psum_scatter(part, ctx.axis_col, scatter_dimension=0, tiled=False)
+    c = _scatter(part, ctx.axis_col)
     res = (a, wg if ctx.cache_weight_gather else w)
     return c, res
 
@@ -455,16 +476,15 @@ def _tess_wt_bwd(ctx, res, dc):
     if effective_schedule(ctx, dc.shape[-2]) == "ring":
         da, dw = _ring_wt_bwd(ctx, a, wr, dc)
     else:
-        wg = wr if ctx.cache_weight_gather else all_gather_inv(wr, ctx.axis_row)
-        dcg = all_gather_inv(dc, ctx.axis_col)      # [q(t), ..., E, G_loc]
+        wg = wr if ctx.cache_weight_gather else _gather_w(ctx, wr)
+        dcg = _gather_a(ctx, dc)                    # [q(t), ..., E, G_loc]
         da = _einsum("t...eg,tgf->...ef", dcg, wg, ctx=ctx, out_dtype=dc.dtype)
         rs_dtype = jnp.bfloat16 if ctx.dgrad_rs_bf16 else jnp.float32
         dw_part = _einsum("t...eg,...ef->tgf", dcg, a, ctx=ctx,
                           out_dtype=rs_dtype)
-        dw = lax.psum_scatter(dw_part, ctx.axis_row, scatter_dimension=0,
-                              tiled=False)
+        dw = _scatter(dw_part, ctx.axis_row)
     if ctx.reduce_dgrad_in_op:
-        dw = lax.psum(dw, _dgrad_axes(ctx))
+        dw = _reduce_dgrad(ctx, dw)
     return da, dw.astype(wr.dtype)
 
 

@@ -19,6 +19,10 @@ from jax import lax
 # innermost of these its name stack holds (``roofline.hlo.scope_of``).
 LAYER_SCOPES = ("embed", "blocks", "attn", "mlp", "head_loss", "optimizer")
 EMBED, BLOCKS, ATTN, MLP, HEAD_LOSS, OPTIMIZER = LAYER_SCOPES
+# The SUMMA matmul's collectives (``core/summa.py``), named inside the layer
+# that calls the matmul.  Not a layer: an op under it still belongs to the
+# layer scope around it (``roofline.hlo.in_scope`` reads it).
+TP_COMM = "tp_comm"
 
 
 # ---------------------------------------------------------------------------

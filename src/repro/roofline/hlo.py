@@ -17,7 +17,8 @@ multiplying every called computation by its trip count.  Elementwise flops
 are ignored (these workloads are dot-dominated; noted in EXPERIMENTS.md).
 
 ``instructions`` lists the module's instructions with their op_name (the
-JAX name stack), and ``scope_of`` reads an op's layer scope from it.
+JAX name stack), ``scope_of`` reads an op's layer scope from it and
+``in_scope`` whether it holds a given scope.
 """
 from __future__ import annotations
 
@@ -217,6 +218,12 @@ def scope_of(op_name: str, fused=()) -> tuple:
         if k[0] is not None:
             votes[k] = votes.get(k, 0) + 1
     return max(votes, key=votes.get) if votes else (None, False)
+
+
+def in_scope(op_name: str, fused, name: str) -> bool:
+    """True where the name stack of an op, or of an op fused into it,
+    holds the scope ``name`` (read as ``scope_of`` reads them)."""
+    return any(name in _name_stack(o) for o in (op_name, *fused))
 
 
 def _group_size(line: str, default: int) -> int:

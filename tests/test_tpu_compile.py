@@ -102,7 +102,7 @@ WORK_OPS = ("fusion", "dot", "convolution", "custom-call", "reduce",
             "scatter")
 
 
-def _train_step_hlo(devices, rows, cols, monkeypatch):
+def _train_step_hlo(devices, rows, cols, monkeypatch, schedule="fused"):
     import repro.kernels.ops as kops
     from repro.configs.base import RunConfig, ShapeSpec
     from repro.core.api import ParallelContext
@@ -113,7 +113,8 @@ def _train_step_hlo(devices, rows, cols, monkeypatch):
     run = RunConfig(param_dtype="float32", compute_dtype="bfloat16",
                     remat="full", attn_impl="pallas")
     ctx = ParallelContext(mode="tesseract", data=1, depth=1, rows=rows,
-                          cols=cols, attn_impl="pallas")
+                          cols=cols, attn_impl="pallas",
+                          matmul_schedule=schedule)
     model = build_model(get_reduced("smollm-360m").model, ctx, run)
     b = build_train_step(model, logical_mesh(ctx, devices),
                          ShapeSpec("t", seq_len=256, global_batch=4,
@@ -146,3 +147,47 @@ def test_train_step_ops_carry_layer_scopes(rows, cols, topo, monkeypatch,
     assert {s for s, _ in kern.values()} == {"attn"}
     fwd = sorted(r for n, (_, r) in kern.items() if n.startswith("flash_fwd"))
     assert fwd == [False, True]
+
+
+# the SUMMA matmul's collectives, fused (all-gathers, reduce-scatters) and
+# ring (collective-permutes), forward and backward, carry ``tp_comm`` inside
+# their layer scope.  Which ops core/summa.py issues is marked here, apart
+# from the program's own scope: each collective it calls runs under a
+# marker scope of this test's.
+MARK = "issued_by_summa"
+
+
+@pytest.mark.parametrize("schedule,kinds", [
+    ("fused", {"all-gather", "reduce-scatter"}),
+    ("ring", {"collective-permute"})])
+def test_summa_collectives_carry_tp_comm(schedule, kinds, topo, monkeypatch,
+                                         no_compile_cache):
+    import re
+    from types import SimpleNamespace
+    from jax import lax
+    import repro.core.summa as summa
+    from repro.models.common import LAYER_SCOPES, TP_COMM
+    from repro.roofline.hlo import in_scope, instructions, scope_of
+    mark = jax.named_scope(MARK)
+    marked_lax = {n: getattr(lax, n) for n in dir(lax) if not n.startswith("_")}
+    marked_lax.update({n: mark(getattr(lax, n))
+                       for n in ("ppermute", "psum_scatter", "psum")})
+    monkeypatch.setattr(summa, "lax", SimpleNamespace(**marked_lax))
+    monkeypatch.setattr(summa, "all_gather_inv", mark(summa.all_gather_inv))
+    text = _train_step_hlo(topo.devices[:4], 2, 2, monkeypatch, schedule)
+    ops = [i for i in instructions(text) if i.runs]
+    issued = [i for i in ops if in_scope(i.op_name, i.fused, MARK)]
+    # the collectives by their own opcode (an async pair by its start)
+    own = {re.sub(r"-start$", "", i.opcode) for i in issued}
+    assert kinds <= own
+    for i in issued:
+        assert in_scope(i.op_name, i.fused, TP_COMM), i.name
+        assert scope_of(i.op_name, i.fused)[0] in LAYER_SCOPES, i.name
+    # nothing else carries it
+    assert [i.name for i in ops if in_scope(i.op_name, i.fused, TP_COMM)
+            and i not in issued] == []
+    # forward and backward
+    names = [n for i in issued for n in (i.op_name, *i.fused)
+             if f"/{MARK}/" in n]
+    assert any("transpose(" in n for n in names)
+    assert any("transpose(" not in n for n in names)

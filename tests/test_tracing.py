@@ -12,8 +12,9 @@ import pytest
 import jax
 
 from repro.data.pipeline import Prefetcher, SyntheticLMStream
-from repro.models.common import LAYER_SCOPES
-from repro.roofline.hlo import instructions, layer_scope, rematted, scope_of
+from repro.models.common import LAYER_SCOPES, TP_COMM
+from repro.roofline.hlo import (in_scope, instructions, layer_scope,
+                                rematted, scope_of)
 
 STEP = "jit(local_step)/transpose(jvp(blocks))/while/body/closed_call"
 
@@ -53,6 +54,23 @@ def test_scope_of_fused_ops():
     assert scope_of("", ("jit(local_step)/lt",)) == (None, False)
     assert set(LAYER_SCOPES) == {"embed", "blocks", "attn", "mlp",
                                  "head_loss", "optimizer"}
+
+
+def test_in_scope():
+    gather = (f"{STEP}/checkpoint/rematted_computation/attn/tp_comm/"
+              "all_gather_invariant")
+    assert in_scope(gather, (), TP_COMM)
+    # an async start names no op itself; the collective fused into it does
+    assert in_scope("", (f"{STEP}/mlp/mul", gather), TP_COMM)
+    # through transform wrappers, a whole segment
+    assert in_scope("jit(step)/transpose(jvp(tp_comm))/reduce_scatter", (),
+                    TP_COMM)
+    assert not in_scope(f"{STEP}/attn/tp_comm_x/mul", (), TP_COMM)
+    assert not in_scope(f"{STEP}/attn/dot_general", (f"{STEP}/mlp/mul",),
+                        TP_COMM)
+    # not a layer: the op belongs to the layer scope around it
+    assert TP_COMM not in LAYER_SCOPES
+    assert scope_of(gather) == ("attn", True)
 
 
 HLO = """HloModule jit_step, is_scheduled=true
